@@ -7,7 +7,9 @@ Run standalone (needs 512 host devices BEFORE jax init):
     PYTHONPATH=src python -m benchmarks.smo_pod_scale
 
 Inside `benchmarks.run` (1-device process) it reports from the cached
-results file if present.
+results file if present; otherwise it compiles in a child process pinned
+to the CPU (the parent may hold the chip), and a failed child fails the
+run.
 """
 from __future__ import annotations
 
@@ -85,6 +87,7 @@ print(json.dumps(out))
 def run():
     env = dict(os.environ)
     env.pop("XLA_FLAGS", None)
+    env["JAX_PLATFORMS"] = "cpu"
     env["PYTHONPATH"] = "src"
     proc = subprocess.run([sys.executable, "-c", _CHILD],
                           capture_output=True, text=True, env=env,
@@ -102,11 +105,7 @@ def main():
     if os.path.exists(RESULTS):
         rows = json.load(open(RESULTS))
     else:
-        try:
-            rows = run()
-        except Exception as e:  # pragma: no cover
-            print(f"smo_pod_scale,error,{str(e)[:120]}")
-            return
+        rows = run()
     for r in rows:
         ledger = (f",ledger_iter_bytes={r['ledger_iter_bytes']}"
                   if "ledger_iter_bytes" in r else "")

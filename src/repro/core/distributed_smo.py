@@ -40,6 +40,7 @@ from typing import Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
+from jax import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from repro.core import engine
@@ -47,7 +48,6 @@ from repro.core.engine.types import SMOResult
 from repro.core.ocssvm import (OCSSVMModel, SlabSpec, concrete_spec,
                                feasible_init)
 from repro.kernels.precision import round_to_tile
-from repro.utils.compat import shard_map
 
 Array = jax.Array
 

@@ -34,6 +34,8 @@ Norms, the f-cache, gamma and all epilogues stay f32
 """
 from __future__ import annotations
 
+from typing import Optional
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -54,11 +56,15 @@ BLOCK = 2048
 
 
 def raw_scores_blocked(X: Array, gamma: Array, kernel: KernelFn,
-                       block: int = BLOCK) -> Array:
-    """K @ gamma without materializing K (row-blocked above the threshold)."""
+                       block: int = BLOCK, *, Y: Optional[Array] = None
+                       ) -> Array:
+    """k(X, Y) @ gamma (Y defaults to X: K @ gamma) without materializing
+    the kernel block: row-blocked over X once it would exceed
+    SINGLE_PASS_MAX^2 entries."""
+    Y = X if Y is None else Y
     m = X.shape[0]
-    if m <= SINGLE_PASS_MAX:
-        return kernel.cross(X, X) @ gamma
+    if m * Y.shape[0] <= SINGLE_PASS_MAX ** 2:
+        return kernel.cross(X, Y) @ gamma
     nblk = (m + block - 1) // block
     pad = nblk * block - m
     Xp = jnp.pad(X, ((0, pad), (0, 0)))
@@ -66,7 +72,7 @@ def raw_scores_blocked(X: Array, gamma: Array, kernel: KernelFn,
     def body(i, acc):
         xb = jax.lax.dynamic_slice_in_dim(Xp, i * block, block)
         return jax.lax.dynamic_update_slice_in_dim(
-            acc, kernel.cross(xb, X) @ gamma, i * block, 0)
+            acc, kernel.cross(xb, Y) @ gamma, i * block, 0)
 
     out = jax.lax.fori_loop(0, nblk, body,
                             jnp.zeros((nblk * block,), gamma.dtype))
@@ -91,7 +97,8 @@ class _ScoreDeltas:
         """f + k(X_own, X_delta) @ g_delta — one pass, no m^2 anything."""
         if X_delta.shape[0] == 0:
             return f
-        return f + self.kernel.rows(self.X, X_delta) @ g_delta
+        return f + raw_scores_blocked(self.X, g_delta, self.kernel,
+                                      Y=X_delta)
 
     def reconcile_scores(self, warm) -> Array:
         """Fold a WarmStart's correction set into its seeded f-cache.
@@ -122,7 +129,7 @@ class _ScoreDeltas:
         p2 = self._rebuilt_extended(Xa)
         gamma2 = jnp.concatenate([jnp.asarray(gamma, jnp.float32), g_app])
         # The appended rows' own scores against the full extended set.
-        f_app = self.kernel.rows(p2.X, Xa).T @ gamma2
+        f_app = raw_scores_blocked(Xa, gamma2, self.kernel, Y=p2.X)
         return p2, gamma2, jnp.concatenate([f_old, f_app])
 
     def expire_rows(self, idx, gamma: Array, f: Array):
@@ -400,7 +407,8 @@ class ShardedGram(_ScoreDeltas):
         if X_delta.shape[0] == 0:
             return f
         if X_delta.shape[0] > BLOCK:
-            return f + self.kernel.rows(self.X, X_delta) @ g_delta
+            return f + raw_scores_blocked(self.X, g_delta, self.kernel,
+                                          Y=X_delta)
         return fupdate(self.X, X_delta, g_delta, f, self.kernel,
                        interpret=self.interpret, precision=self.precision)
 

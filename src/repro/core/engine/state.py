@@ -254,6 +254,7 @@ def prepare_warm_start(prev: SolverArtifact, X_new, spec, *,
     rounded to those tiles so the sweep sees the same Gram entries the
     provider streams.
     """
+    from repro.core.engine.gram import raw_scores_blocked
     from repro.core.ocssvm import concrete_spec
     from repro.kernels.precision import round_to_tile
     spec = concrete_spec(spec)
@@ -279,12 +280,14 @@ def prepare_warm_start(prev: SolverArtifact, X_new, spec, *,
     g_exp = prev.gamma[prev_exp].astype(np.float32)
 
     if new_fresh.size:
-        # Appended rows' score under C: one O(dm * (m + e) * d) pass.
+        # Appended rows' score under C: one O(dm * (m + e) * d) pass,
+        # row-blocked so no dm x m kernel block is ever materialized.
         Xf = Xr[jnp.asarray(new_fresh)]
-        s_fresh = spec.kernel.cross(Xf, Xr) @ jnp.asarray(g_assumed)
+        s_fresh = raw_scores_blocked(Xf, jnp.asarray(g_assumed),
+                                     spec.kernel, Y=Xr)
         if prev_exp.size:
-            s_fresh = s_fresh + spec.kernel.cross(Xf, X_exp) @ jnp.asarray(
-                g_exp)
+            s_fresh = s_fresh + raw_scores_blocked(
+                Xf, jnp.asarray(g_exp), spec.kernel, Y=X_exp)
         f_seed[new_fresh] = np.asarray(s_fresh, np.float32)
 
     gamma0 = clip_to_box(g_assumed, hi=hi, lo=lo, total=total)

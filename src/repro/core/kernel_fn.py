@@ -8,6 +8,15 @@ All kernels expose three access patterns the SMO solver needs:
                        this is what the Pallas ``fupdate`` kernel fuses).
 
 Everything is pure jnp and jit-friendly; the kernel choice is static.
+
+The dot products run at ``Precision.HIGHEST`` (``dot``): XLA's default
+on a TPU computes an f32 matmul in one bf16 pass, and the RBF distance
+``||x||^2 + ||y||^2 - 2 x.y`` cancels that error into the kernel value,
+so the shrinking driver's KKT sweeps disagree with the f32 Pallas hot
+loop. ``ocssvm.py``'s dual objectives and ``raw_scores`` use ``dot``
+for their matvecs too, so the checks that read them are full f32; the
+solver's own K @ gamma matvecs keep XLA's default. On the CPU it
+changes nothing.
 """
 from __future__ import annotations
 
@@ -16,6 +25,15 @@ import jax
 import jax.numpy as jnp
 
 Array = jax.Array
+
+
+def dot(a: Array, b: Array) -> Array:
+    """a @ b in full f32 on every backend (see the module docstring)."""
+    return jnp.matmul(a, b, precision=jax.lax.Precision.HIGHEST)
+
+
+def _dot_t(X: Array, Y: Array) -> Array:
+    return dot(X, Y.T)
 
 
 @jax.tree_util.register_pytree_node_class
@@ -47,15 +65,15 @@ class KernelFn:
     def cross(self, X: Array, Y: Array) -> Array:
         """K[i, j] = k(X[i], Y[j]); shapes (m, d), (n, d) -> (m, n)."""
         if self.name == "linear":
-            return X @ Y.T
+            return _dot_t(X, Y)
         if self.name == "rbf":
             # ||x - y||^2 = ||x||^2 + ||y||^2 - 2 x.y, computed in f32.
             xx = jnp.sum(X * X, axis=-1, keepdims=True)
             yy = jnp.sum(Y * Y, axis=-1, keepdims=True)
-            sq = xx + yy.T - 2.0 * (X @ Y.T)
+            sq = xx + yy.T - 2.0 * _dot_t(X, Y)
             return jnp.exp(-self.gamma * jnp.maximum(sq, 0.0))
         if self.name == "poly":
-            return (self.gamma * (X @ Y.T) + self.coef0) ** self.degree
+            return (self.gamma * _dot_t(X, Y) + self.coef0) ** self.degree
         raise ValueError(f"unknown kernel {self.name!r}")
 
     def gram(self, X: Array) -> Array:

@@ -20,7 +20,7 @@ import numpy as np
 
 from repro.core.engine import stats as _stats
 from repro.core.engine.gram import SINGLE_PASS_MAX
-from repro.core.kernel_fn import KernelFn
+from repro.core.kernel_fn import KernelFn, dot
 
 Array = jax.Array
 
@@ -66,7 +66,7 @@ class OCSSVMModel(NamedTuple):
 
     def raw_scores(self, Xq: Array) -> Array:
         """s(x) = sum_j gamma_j k(x, x_j) for query points (n, d) -> (n,)."""
-        return self.spec.kernel.cross(Xq, self.X) @ self.gamma
+        return dot(self.spec.kernel.cross(Xq, self.X), self.gamma)
 
     def decision_function(self, Xq: Array) -> Array:
         """Signed slab margin value (eq. 19 before the sgn)."""
@@ -200,7 +200,7 @@ def compact_support(model: "OCSSVMModel",
 
 def dual_objective(gamma: Array, K: Array) -> Array:
     """1/2 gamma^T K gamma (eq. 30)."""
-    return 0.5 * gamma @ (K @ gamma)
+    return 0.5 * dot(gamma, dot(K, gamma))
 
 
 def dual_objective_matfree(gamma: Array, X: Array, kernel: KernelFn) -> Array:
@@ -211,7 +211,7 @@ def dual_objective_matfree(gamma: Array, X: Array, kernel: KernelFn) -> Array:
     the quadratic form is accumulated over row blocks.
     """
     if X.shape[0] <= SINGLE_PASS_MAX:
-        return 0.5 * gamma @ (kernel.cross(X, X) @ gamma)
+        return 0.5 * dot(gamma, dot(kernel.cross(X, X), gamma))
     return _blocked_obj(gamma, X, kernel)
 
 
@@ -225,6 +225,6 @@ def _blocked_obj(gamma: Array, X: Array, kernel: KernelFn, block: int = 2048) ->
     def body(i, acc):
         xb = jax.lax.dynamic_slice_in_dim(Xp, i * block, block)
         gb = jax.lax.dynamic_slice_in_dim(gp, i * block, block)
-        return acc + gb @ (kernel.cross(xb, Xp) @ gp)
+        return acc + dot(gb, dot(kernel.cross(xb, Xp), gp))
 
     return 0.5 * jax.lax.fori_loop(0, nblk, body, jnp.zeros((), gamma.dtype))

@@ -45,6 +45,7 @@ from typing import Optional, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 from repro.core.batched_smo import solve_blocked
@@ -55,7 +56,6 @@ from repro.core.engine.types import SMOResult
 from repro.core.ocssvm import (OCSSVMModel, SlabSpec, concrete_spec,
                                recover_rhos)
 from repro.kernels.precision import round_to_tile
-from repro.utils.compat import shard_map
 
 Array = jax.Array
 

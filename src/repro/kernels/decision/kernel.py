@@ -13,6 +13,8 @@ queries per request; the support block is packed in the serving dtype once
 at model-pack time); ``dot_general`` accumulates via
 ``preferred_element_type=jnp.float32`` and gamma, the norms, the VMEM
 accumulator and the slab epilogue stay f32.
+f32 operands contract at full f32 (``mxu_precision``): Mosaic's default
+is one bf16 pass.
 """
 from __future__ import annotations
 
@@ -22,6 +24,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+
+from repro.kernels.precision import mxu_precision
 
 
 def _decision_kernel(rho_ref, qn_ref, tn_ref, gamma_ref, q_ref, t_ref,
@@ -36,6 +40,7 @@ def _decision_kernel(rho_ref, qn_ref, tn_ref, gamma_ref, q_ref, t_ref,
     q = q_ref[...]          # (TM, D)
     t = t_ref[...]          # (TN, D)
     dot = jax.lax.dot_general(q, t, (((1,), (1,)), ((), ())),
+                              precision=mxu_precision(q.dtype),
                               preferred_element_type=jnp.float32)
     if kind == "rbf":
         sq = qn_ref[...] + tn_ref[...].T - 2.0 * dot
@@ -44,7 +49,8 @@ def _decision_kernel(rho_ref, qn_ref, tn_ref, gamma_ref, q_ref, t_ref,
         krows = (gamma * dot + coef0) ** degree
     else:
         krows = dot
-    acc_ref[...] += krows @ gamma_ref[...]
+    acc_ref[...] += jnp.dot(krows, gamma_ref[...],
+                            precision=mxu_precision(jnp.float32))
 
     @pl.when(j == nj - 1)
     def _epilogue():

@@ -28,7 +28,7 @@ from repro.core.kernel_fn import KernelFn
 from repro.kernels.tiling import (_auto_interpret, _pad_to, backend_name,
                                   resolve_tiles)
 from repro.kernels.decision.kernel import decision_pallas
-from repro.kernels.precision import tile_dtype
+from repro.kernels.precision import check_launch_precision, tile_dtype
 
 
 @partial(jax.jit, static_argnames=("kernel", "tm", "tn", "interpret",
@@ -59,6 +59,7 @@ def decision(q, t, gamma_vec, rho1, rho2, kernel: KernelFn, *,
     """
     if interpret is None:
         interpret = _auto_interpret()
+    check_launch_precision(precision, interpret)
     cfg = resolve_tiles("decision", m=t.shape[0], d=t.shape[1],
                         precision=precision,
                         backend=backend_name(interpret),
@@ -107,6 +108,7 @@ def decision_packed(q_pad, t_pad, gamma_pad, t_norms, rho1, rho2,
     """
     if interpret is None:
         interpret = _auto_interpret()
+    check_launch_precision(precision, interpret)
     dt = tile_dtype(precision)
     if q_pad.shape[0] % tm or t_pad.shape[0] % tn or q_pad.shape[1] % 128:
         raise ValueError(

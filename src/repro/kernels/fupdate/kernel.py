@@ -16,6 +16,8 @@ Mixed precision: the x / x_sel data tiles may arrive in bf16/f16 (ops.py
 casts them once — the X stream is the whole per-iteration HBM bill);
 ``dot_general`` accumulates via ``preferred_element_type=jnp.float32`` and
 the norms, delta/f operands, scratch accumulator and epilogue stay f32.
+f32 operands contract at full f32 (``mxu_precision``): Mosaic's default
+is one bf16 pass.
 """
 from __future__ import annotations
 
@@ -25,6 +27,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+
+from repro.kernels.precision import mxu_precision
 
 
 def _fupdate_kernel(xn_ref, seln_ref, delta_ref, f_ref, x_ref, xsel_ref,
@@ -40,6 +44,7 @@ def _fupdate_kernel(xn_ref, seln_ref, delta_ref, f_ref, x_ref, xsel_ref,
     xs = xsel_ref[...]      # (2P, TK)
     acc_ref[...] += jax.lax.dot_general(
         x, xs, (((1,), (1,)), ((), ())),
+        precision=mxu_precision(x.dtype),
         preferred_element_type=jnp.float32)
 
     @pl.when(k == nk - 1)
@@ -52,7 +57,8 @@ def _fupdate_kernel(xn_ref, seln_ref, delta_ref, f_ref, x_ref, xsel_ref,
             krows = (gamma * dot + coef0) ** degree
         else:
             krows = dot
-        out_ref[...] = f_ref[...] + krows @ delta_ref[...]
+        out_ref[...] = f_ref[...] + jnp.dot(
+            krows, delta_ref[...], precision=mxu_precision(jnp.float32))
 
 
 def fupdate_pallas(x, xsel, delta, f, xn, seln, *, kind: str, gamma: float,

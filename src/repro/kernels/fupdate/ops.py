@@ -24,7 +24,7 @@ from repro.core.kernel_fn import KernelFn
 from repro.kernels.tiling import (_auto_interpret, _pad_to, backend_name,
                                   resolve_tiles)
 from repro.kernels.fupdate.kernel import fupdate_pallas
-from repro.kernels.precision import tile_dtype
+from repro.kernels.precision import check_launch_precision, tile_dtype
 
 
 @partial(jax.jit, static_argnames=("kernel", "tm", "tk", "interpret",
@@ -55,6 +55,7 @@ def fupdate(x, xsel, delta, f, kernel: KernelFn, *, tm: int | None = None,
     """
     if interpret is None:
         interpret = _auto_interpret()
+    check_launch_precision(precision, interpret)
     cfg = resolve_tiles("fupdate", m=x.shape[0], d=x.shape[1],
                         precision=precision,
                         backend=backend_name(interpret),

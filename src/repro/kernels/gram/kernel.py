@@ -14,6 +14,8 @@ Mixed precision: the x/y data tiles may arrive in bf16/f16 (ops.py casts
 them once, halving the HBM stream); ``dot_general`` still accumulates via
 ``preferred_element_type=jnp.float32``, and the norm operands, accumulator
 and epilogue are always f32 — only the streamed bytes shrink.
+f32 operands contract at full f32 (``mxu_precision``): Mosaic's default
+is one bf16 pass.
 """
 from __future__ import annotations
 
@@ -22,6 +24,8 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+
+from repro.kernels.precision import mxu_precision
 
 
 def _gram_kernel(xn_ref, yn_ref, x_ref, y_ref, out_ref, *, nk: int,
@@ -36,6 +40,7 @@ def _gram_kernel(xn_ref, yn_ref, x_ref, y_ref, out_ref, *, nk: int,
     y = y_ref[...]
     out_ref[...] += jax.lax.dot_general(
         x, y, (((1,), (1,)), ((), ())),
+        precision=mxu_precision(x.dtype),
         preferred_element_type=jnp.float32)
 
     @pl.when(k == nk - 1)

@@ -28,7 +28,7 @@ import jax.numpy as jnp
 
 from repro.core.kernel_fn import KernelFn
 from repro.kernels.gram.kernel import gram_pallas
-from repro.kernels.precision import tile_dtype
+from repro.kernels.precision import check_launch_precision, tile_dtype
 # Re-exported for backward compatibility: these moved to kernels.tiling so
 # sibling kernel families stop importing through this module (import-cycle
 # hazard when repro.kernels is the first package imported).
@@ -61,6 +61,7 @@ def gram(x, y, kernel: KernelFn, *, tm: int | None = None,
     """
     if interpret is None:
         interpret = _auto_interpret()
+    check_launch_precision(precision, interpret)
     cfg = resolve_tiles("gram", m=max(x.shape[0], y.shape[0]),
                         d=x.shape[1], precision=precision,
                         backend=backend_name(interpret),

@@ -20,6 +20,11 @@ matters:
 ``precision="f32"`` is the default and is a no-op cast: the compute
 graph is bit-identical to the pre-knob kernels (tests assert it).
 
+``"f16"`` runs in interpret mode only: the TPU kernel compiler (Mosaic)
+refuses f16 vector loads on v5e ("Invalid vector type for load"), so a
+compiled launch asking for it is refused up front by
+:func:`check_launch_precision` instead of failing inside the compiler.
+
 The product of two bf16 (8 mantissa bits) or f16 (11 bits) values is
 exactly representable in f32 (<= 22 bits), so the only error sources
 are the input rounding and the f32 accumulation order — which is why
@@ -28,6 +33,7 @@ the Pallas kernels to tight per-dtype tolerances (``TOLERANCES``).
 """
 from __future__ import annotations
 
+import jax
 import jax.numpy as jnp
 
 # Public knob values, in "fastest-safe first" documentation order.
@@ -67,6 +73,33 @@ def check_precision(precision: str) -> str:
         raise ValueError(f"unknown precision {precision!r}; "
                          f"expected one of {PRECISIONS}")
     return precision
+
+
+def check_launch_precision(precision: str, interpret: bool) -> str:
+    """``check_precision`` plus the compiled-kernel rule: f16 tiles are
+    interpret-only (Mosaic refuses f16 vector loads on v5e)."""
+    check_precision(precision)
+    if precision == "f16" and not interpret:
+        device = jax.devices()[0]
+        raise ValueError(
+            f"precision='f16' cannot launch a compiled Pallas kernel on "
+            f"{device.platform}:{device.device_kind}: the TPU kernel "
+            f"compiler refuses f16 vector loads on v5e ('Invalid vector "
+            f"type for load'). Use 'bf16' (same 16-bit stream) or "
+            f"interpret=True.")
+    return precision
+
+
+def mxu_precision(dtype):
+    """Contract precision for an in-kernel dot on ``dtype`` operands.
+
+    Mosaic's default contracts f32 operands in one bf16 pass on v5e: on
+    the chip, f32 ``fupdate`` kernel values were off by up to 3.8e-3 and
+    the fit at 284,807 rows never converged. So f32 operands ask for full
+    f32. 16-bit operands keep the default: their products are exact in
+    f32 already."""
+    return jax.lax.Precision.HIGHEST if jnp.dtype(dtype) == jnp.float32 \
+        else None
 
 
 def parse_precisions(spec: str) -> tuple:

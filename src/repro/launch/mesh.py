@@ -12,6 +12,18 @@ import math
 from typing import Tuple
 
 import jax
+from jax.sharding import AxisType
+
+
+def _auto_mesh(shape, axes, devices):
+    """``jax.make_mesh`` with every axis ``Auto``: since jax 0.9 the
+    default is ``Explicit``, which makes eager indexing of a
+    mesh-sharded array demand an ``out_sharding`` (the shrinking
+    driver's active-row gather) — the solver and scorer place their
+    operands themselves and want the compiler to propagate layouts."""
+    return jax.make_mesh(shape, axes,
+                         axis_types=(AxisType.Auto,) * len(shape),
+                         devices=devices)
 
 
 def make_production_mesh(*, multi_pod: bool = False):
@@ -24,13 +36,13 @@ def make_production_mesh(*, multi_pod: bool = False):
             f"mesh {shape} needs {n} devices, found {len(devices)} — "
             "set XLA_FLAGS=--xla_force_host_platform_device_count "
             "before importing jax (dryrun.py does this)")
-    return jax.make_mesh(shape, axes, devices=devices[:n])
+    return _auto_mesh(shape, axes, devices[:n])
 
 
 def make_test_mesh(shape: Tuple[int, ...], axes: Tuple[str, ...]):
     """Small meshes for CPU tests (e.g. (1,1) or (2,2))."""
     n = math.prod(shape)
-    return jax.make_mesh(shape, axes, devices=jax.devices()[:n])
+    return _auto_mesh(shape, axes, jax.devices()[:n])
 
 
 def make_solver_mesh(*, multi_pod: bool = False, devices=None):
@@ -60,8 +72,8 @@ def make_solver_mesh(*, multi_pod: bool = False, devices=None):
             raise RuntimeError(
                 f"multi_pod solver mesh needs an even device count >= 2, "
                 f"found {n}")
-        mesh = jax.make_mesh((2, n // 2, 1), ("pod", "data", "model"),
-                             devices=devices)
+        mesh = _auto_mesh((2, n // 2, 1), ("pod", "data", "model"),
+                          devices)
     else:
-        mesh = jax.make_mesh((n, 1), ("data", "model"), devices=devices)
+        mesh = _auto_mesh((n, 1), ("data", "model"), devices)
     return mesh, data_axes

@@ -39,6 +39,7 @@ import numpy as np
 import repro
 from repro.core import SlabSpec, linear, poly, rbf
 from repro.data import make_toy
+from repro.launch.compile_cache import use_compile_cache
 from repro.launch.mesh import make_test_mesh
 from repro.serve import (AdmissionController, AsyncDriver, ModelRegistry,
                          QuotaExceededError, ScoringService, attach,
@@ -293,4 +294,5 @@ def main(argv=None):
 
 
 if __name__ == "__main__":
+    use_compile_cache()
     main()

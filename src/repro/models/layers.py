@@ -5,9 +5,9 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
+from jax import shard_map
 from jax.sharding import PartitionSpec as P
 
-from repro.utils.compat import shard_map
 
 Array = jax.Array
 

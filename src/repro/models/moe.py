@@ -22,9 +22,9 @@ from typing import Tuple
 
 import jax
 import jax.numpy as jnp
+from jax import shard_map
 
 from repro.models.layers import dense_init
-from repro.utils.compat import shard_map
 
 Array = jax.Array
 

@@ -23,10 +23,10 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax import shard_map
 
 from repro.kernels.decision.ops import decision_packed
 from repro.serve.model_cache import ServingModel
-from repro.utils.compat import shard_map
 
 Array = jax.Array
 
@@ -69,6 +69,18 @@ class BatchScorer:
         if mesh is not None and data_axis not in mesh.shape:
             raise ValueError(f"mesh has no axis {data_axis!r}: "
                              f"{tuple(mesh.shape)}")
+        if mesh is not None:
+            # Replicate the packed support set onto the mesh once: a
+            # model fitted on one committed device could not otherwise
+            # enter a program that spans the mesh.
+            self._replicated = jax.device_put(
+                (model.t_pad, model.gamma_pad, model.t_norms, model.rho1,
+                 model.rho2),
+                jax.sharding.NamedSharding(mesh,
+                                           jax.sharding.PartitionSpec()))
+        # per-shard bucket -> the jitted shard_map'd launch: built once,
+        # so a warm bucket never traces or compiles again
+        self._sharded_fns: dict = {}
 
     # -- padding ------------------------------------------------------------
     def _pad_queries(self, q, rows: int) -> Array:
@@ -174,26 +186,30 @@ class BatchScorer:
         return self._unpad(out, n, host)
 
     # -- sharded path -------------------------------------------------------
+    def _sharded_fn(self, per_shard: int):
+        fn = self._sharded_fns.get(per_shard)
+        if fn is None:
+            m = self.model
+            P = jax.sharding.PartitionSpec
+
+            def shard_fn(qs, t_pad, gamma_pad, t_norms, rho1, rho2):
+                return decision_packed(qs, t_pad, gamma_pad, t_norms, rho1,
+                                       rho2, m.spec.kernel,
+                                       tm=self._tm(per_shard), tn=m.tn,
+                                       interpret=self.interpret,
+                                       precision=m.precision)
+
+            fn = self._sharded_fns[per_shard] = jax.jit(shard_map(
+                shard_fn, mesh=self.mesh,
+                in_specs=(P(self.data_axis, None),) + (P(),) * 5,
+                out_specs=P(self.data_axis), check_vma=False))
+        return fn
+
     def _score_sharded(self, q, n: int) -> Array:
-        mesh = self.mesh
-        nd = int(mesh.shape[self.data_axis])
+        nd = int(self.mesh.shape[self.data_axis])
         per_shard = bucket_for(max(1, -(-n // nd)))
         q_pad = self._pad_queries(q, per_shard * nd)
-        m = self.model
-        P = jax.sharding.PartitionSpec
-
-        def shard_fn(qs):
-            return decision_packed(qs, m.t_pad, m.gamma_pad, m.t_norms,
-                                   m.rho1, m.rho2, m.spec.kernel,
-                                   tm=self._tm(per_shard), tn=m.tn,
-                                   interpret=self.interpret,
-                                   precision=m.precision)
-
-        fn = shard_map(shard_fn, mesh=mesh,
-                       in_specs=(P(self.data_axis, None),),
-                       out_specs=P(self.data_axis))
-        with mesh:
-            out = fn(q_pad)
+        out = self._sharded_fn(per_shard)(q_pad, *self._replicated)
         return self._unpad(out, n, isinstance(q, np.ndarray))
 
     def warmup(self) -> None:

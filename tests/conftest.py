@@ -5,7 +5,8 @@ needs >1 jax device: jax pins the device count at first import, and the
 main pytest process must stay single-device, so multi-device cells run
 their payload in a child python with
 ``XLA_FLAGS=--xla_force_host_platform_device_count=N`` and report back as
-a JSON line on stdout.
+a JSON line on stdout. The child is pinned to the CPU: on a machine
+with a chip it must never reach for the device the parent may hold.
 """
 import json
 import os
@@ -22,6 +23,7 @@ def run_forced_devices(code: str, devices: int = 4,
     devices; returns the JSON object printed on its last stdout line."""
     env = dict(os.environ)
     env["XLA_FLAGS"] = f"--xla_force_host_platform_device_count={devices}"
+    env["JAX_PLATFORMS"] = "cpu"
     env["PYTHONPATH"] = REPO_SRC
     out = subprocess.run([sys.executable, "-c", textwrap.dedent(code)],
                          capture_output=True, text=True, env=env,

@@ -270,9 +270,9 @@ def test_two_process_jax_distributed_smoke():
     """2-process jax.distributed bring-up on CPU: both processes must
     initialize against one coordinator, see the global 2-device topology,
     and — where the jax build supports cross-process CPU collectives —
-    agree on a process_allgather. jax 0.4.37 (the CI floor) reports
-    multiprocess CPU computations as unimplemented; the smoke still gates
-    coordinator + topology there."""
+    agree on a process_allgather. A jax build that reports multiprocess
+    CPU computations as unimplemented still gates coordinator +
+    topology."""
     import socket
 
     s = socket.socket()
@@ -330,9 +330,9 @@ def test_compressed_gradient_allreduce():
     res = _run("""
         import json
         import jax, jax.numpy as jnp
+        from jax import shard_map
         from jax.sharding import PartitionSpec as P
         from repro.optim.compression import init_error_state, psum_compressed
-        from repro.utils.compat import shard_map
         mesh = jax.make_mesh((4,), ("data",))
         g = {"w": jnp.arange(32, dtype=jnp.float32).reshape(4, 8) / 7.0}
         err = init_error_state(g)

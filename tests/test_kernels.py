@@ -30,6 +30,59 @@ def _tol(dtype):
 
 
 @pytest.mark.parametrize("kern", KERNELS, ids=lambda k: k.name)
+def test_kernel_fn_dots_request_full_f32(kern):
+    """The jnp kernel evaluations the solver runs outside Pallas must ask
+    for HIGHEST precision: on a TPU the default computes an f32 matmul
+    in one bf16 pass."""
+    X = jnp.ones((8, 3), jnp.float32)
+    jaxpr = jax.make_jaxpr(kern.cross)(X, X)
+    dots = [e for e in jaxpr.jaxpr.eqns if e.primitive.name == "dot_general"]
+    assert dots
+    hi = jax.lax.Precision.HIGHEST
+    for e in dots:
+        assert e.params["precision"] in (hi, (hi, hi)), e.params["precision"]
+
+
+def _dot_generals(jaxpr):
+    """Every dot_general in ``jaxpr`` and its sub-jaxprs (the Pallas
+    kernel body included)."""
+    for e in jaxpr.eqns:
+        if e.primitive.name == "dot_general":
+            yield e
+        for v in e.params.values():
+            for sub in (v if isinstance(v, (tuple, list)) else (v,)):
+                sub = getattr(sub, "jaxpr", sub)
+                if hasattr(sub, "eqns"):
+                    yield from _dot_generals(sub)
+
+
+@pytest.mark.parametrize("precision", ["f32", "bf16"])
+@pytest.mark.parametrize("family", ["gram", "fupdate", "decision"])
+def test_pallas_f32_dots_request_full_f32(family, precision):
+    """Every in-kernel dot on f32 operands asks for HIGHEST: Mosaic's
+    default on a TPU contracts f32 in one bf16 pass. 16-bit tiles keep
+    the default (their products are exact in f32)."""
+    kern = rbf(gamma=0.35)
+    X = jnp.ones((16, 8), jnp.float32)
+    v = jnp.ones((16,), jnp.float32)
+    fn = {"gram": lambda: gram(X, X, kern, interpret=True,
+                               precision=precision),
+          "fupdate": lambda: fupdate(X, X[:4], v[:4], v, kern,
+                                     interpret=True, precision=precision),
+          "decision": lambda: decision(X, X, v, 0.1, 0.2, kern,
+                                       interpret=True, precision=precision),
+          }[family]
+    dots = list(_dot_generals(jax.make_jaxpr(fn)().jaxpr))
+    assert dots
+    hi = jax.lax.Precision.HIGHEST
+    for e in dots:
+        f32 = all(a.aval.dtype == jnp.float32 for a in e.invars)
+        full = e.params["precision"] in (hi, (hi, hi))
+        assert full == f32, (e.params["precision"],
+                             [a.aval.dtype for a in e.invars])
+
+
+@pytest.mark.parametrize("kern", KERNELS, ids=lambda k: k.name)
 @pytest.mark.parametrize("shape", SHAPES)
 @pytest.mark.parametrize("dtype", DTYPES, ids=str)
 def test_gram_matches_ref(kern, shape, dtype):

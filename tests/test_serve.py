@@ -472,6 +472,30 @@ def test_warmup_compiles_the_served_path(served, monkeypatch):
     assert calls["local"] == 0
 
 
+def test_sharded_scorer_warm_bucket_never_recompiles(served):
+    """After warmup, sharded scoring of a warm bucket lowers no program:
+    the shard_map'd launch is built once per bucket, not per call (each
+    call used to re-trace and re-compile it)."""
+    mesh = jax.make_mesh((1,), ("data",))
+    scorer = served.scorer(mesh=mesh)
+    scorer.warmup()
+    q = np.asarray(make_toy(jax.random.PRNGKey(12), 100)[0])
+    lowered = []
+
+    def listen(name, _secs, **_kw):
+        if name == "/jax/core/compile/jaxpr_to_mlir_module_duration":
+            lowered.append(name)
+
+    jax.monitoring.register_event_duration_secs_listener(listen)
+    try:
+        for _ in range(3):
+            out = scorer.score(q)
+    finally:
+        jax.monitoring.unregister_event_duration_listener(listen)
+    assert out.shape == (100,)
+    assert lowered == []
+
+
 def test_warmup_local_matches_serving_buckets(served):
     """Local warmup still covers every bucket and a post-warmup score
     agrees with the reference."""

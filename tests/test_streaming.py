@@ -414,3 +414,20 @@ def test_provider_append_expire_matches_rebuild(gram_mode):
                                rtol=0, atol=5e-6)
     np.testing.assert_allclose(np.asarray(g3),
                                np.asarray(g2)[keep], rtol=0, atol=0)
+
+
+@pytest.mark.parametrize("nq,n", [(5000, 4000), (40, 600), (4100, 4100)],
+                         ids=["blocked", "one-pass", "square"])
+def test_raw_scores_blocked_against_other_rows(nq, n):
+    """k(Xq, Y) @ g in row blocks (the warm start's appended-row and
+    correction passes) equals the dense product, whether or not the
+    block would have fitted in one pass."""
+    k1, k2, k3 = jax.random.split(jax.random.PRNGKey(11), 3)
+    Xq = jax.random.normal(k1, (nq, 3), jnp.float32)
+    Y = Xq if nq == n else jax.random.normal(k2, (n, 3), jnp.float32)
+    g = jax.random.uniform(k3, (n,), jnp.float32)
+    kern = rbf(gamma=0.5)
+    got = engine.raw_scores_blocked(Xq, g, kern, Y=Y)
+    want = kern.cross(Xq, Y) @ g
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                               rtol=1e-5, atol=1e-5)

@@ -24,6 +24,7 @@ from typing import Optional, Tuple
 
 import jax
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from repro.core.batched_smo import solve_blocked
 from repro.core.distributed_smo import solve_blocked_distributed
@@ -109,110 +110,112 @@ def fit(
     written into. Extra kwargs flow to the chosen solver
     (max_iters/max_outer, patience, gamma0, ...).
     """
-    if spec is None:
-        spec = SlabSpec()
-    if strategy not in STRATEGIES:
-        raise ValueError(f"unknown strategy {strategy!r}; "
-                         f"expected one of {STRATEGIES}")
-    m = X.shape[0]
+    with TraceAnnotation("fit"):        # the whole call (profiler clock)
+        if spec is None:
+            spec = SlabSpec()
+        if strategy not in STRATEGIES:
+            raise ValueError(f"unknown strategy {strategy!r}; "
+                             f"expected one of {STRATEGIES}")
+        m = X.shape[0]
 
-    warm = None
-    if warm_start is not None:
-        if isinstance(warm_start, WarmStart):
-            warm = warm_start          # prepared by the caller (fit_update)
-        else:
-            art = _as_artifact(warm_start, precision=precision)
-            warm, winfo = prepare_warm_start(art, X, spec,
-                                             precision=precision)
-            if warm_info_out is not None:
-                warm_info_out.update(dataclasses.asdict(winfo))
+        warm = None
+        if warm_start is not None:
+            if isinstance(warm_start, WarmStart):
+                warm = warm_start      # prepared by the caller (fit_update)
+            else:
+                art = _as_artifact(warm_start, precision=precision)
+                warm, winfo = prepare_warm_start(art, X, spec,
+                                                 precision=precision)
+                if warm_info_out is not None:
+                    warm_info_out.update(dataclasses.asdict(winfo))
 
-    if strategy == "auto":
-        if mesh is not None:
-            strategy = "sharded"
-        elif m > _SHRINKING_MIN_M:
-            strategy = "shrinking"
-        else:
-            strategy = "blocked"
+        if strategy == "auto":
+            if mesh is not None:
+                strategy = "sharded"
+            elif m > _SHRINKING_MIN_M:
+                strategy = "shrinking"
+            else:
+                strategy = "blocked"
 
-    # The sequential solvers call their iteration cap max_iters, the
-    # blocked family max_outer; accept either so "auto" can reroute a call
-    # without the caller caring which solver won.
-    if strategy in ("paper", "mvp"):
-        if "max_outer" in kwargs:
-            kwargs["max_iters"] = kwargs.pop("max_outer")
-    elif "max_iters" in kwargs:
-        kwargs["max_outer"] = kwargs.pop("max_iters")
+        # The sequential solvers call their iteration cap max_iters, the
+        # blocked family max_outer; accept either so "auto" can reroute a call
+        # without the caller caring which solver won.
+        if strategy in ("paper", "mvp"):
+            if "max_outer" in kwargs:
+                kwargs["max_iters"] = kwargs.pop("max_outer")
+        elif "max_iters" in kwargs:
+            kwargs["max_outer"] = kwargs.pop("max_iters")
 
-    if strategy in ("distributed", "sharded"):
-        if gram_mode is not None:
-            raise ValueError(
-                "gram_mode is not configurable for the sharded/"
-                "distributed strategies: the sharded provider owns Gram "
-                "access (its hot loop is the per-shard Pallas fupdate; "
-                "the local repack solves of the sharded shrinking driver "
-                "pick their own provider)")
-        if strategy == "distributed" and mesh is None:
-            raise ValueError("strategy='distributed' needs a mesh; "
-                             "use strategy='sharded' to build one from "
-                             "the launch layer")
-        if mesh is None:
-            from repro.launch.mesh import make_solver_mesh
-            mesh, data_axes = make_solver_mesh(multi_pod=multi_pod)
-        if strategy == "sharded" and m > _SHRINKING_MIN_M:
-            return solve_sharded_shrinking(X, spec, mesh,
-                                           data_axes=data_axes,
-                                           P_pairs=P, tol=tol,
-                                           precision=precision,
+        if strategy in ("distributed", "sharded"):
+            if gram_mode is not None:
+                raise ValueError(
+                    "gram_mode is not configurable for the sharded/"
+                    "distributed strategies: the sharded provider owns Gram "
+                    "access (its hot loop is the per-shard Pallas fupdate; "
+                    "the local repack solves of the sharded shrinking driver "
+                    "pick their own provider)")
+            if strategy == "distributed" and mesh is None:
+                raise ValueError("strategy='distributed' needs a mesh; "
+                                 "use strategy='sharded' to build one from "
+                                 "the launch layer")
+            if mesh is None:
+                from repro.launch.mesh import make_solver_mesh
+                mesh, data_axes = make_solver_mesh(multi_pod=multi_pod)
+            if strategy == "sharded" and m > _SHRINKING_MIN_M:
+                return solve_sharded_shrinking(X, spec, mesh,
+                                               data_axes=data_axes,
+                                               P_pairs=P, tol=tol,
+                                               precision=precision,
+                                               interpret=interpret,
+                                               ledger=ledger, warm=warm,
+                                               **kwargs)
+            # Below the shrinking threshold the plain sharded solve runs;
+            # surface a clear error for shrinking-only knobs instead of an
+            # opaque TypeError (the accepted kwargs must not silently change
+            # when a growing dataset crosses the threshold).
+            shrink_only = [k for k in ("warm_iters", "max_rounds",
+                                       "round_iters", "margin", "gather_max")
+                           if k in kwargs]
+            if shrink_only:
+                raise ValueError(
+                    f"kwargs {shrink_only} configure the sharded shrinking "
+                    f"driver, which only runs for m > {_SHRINKING_MIN_M} "
+                    f"(got m={m}); drop them or call "
+                    "repro.core.solve_sharded_shrinking directly")
+            return solve_blocked_distributed(X, spec, mesh,
+                                             data_axes=data_axes, P_pairs=P,
+                                             tol=tol, precision=precision,
+                                             interpret=interpret,
+                                             ledger=ledger, warm=warm,
+                                             **kwargs)
+
+        if strategy == "pallas":
+            if gram_mode is not None and gram_mode != "pallas":
+                raise ValueError(
+                    f"strategy='pallas' pins gram_mode='pallas'; got "
+                    f"gram_mode={gram_mode!r} — drop it or use "
+                    f"strategy='blocked'")
+            return solve_blocked(X, spec, P=P, gram_mode="pallas",
+                                 interpret=interpret, precision=precision,
+                                 tol=tol, warm=warm, **kwargs)
+
+        gm = (gram_mode if gram_mode is not None
+              else _auto_gram_mode(m, interpret))
+        if strategy in ("paper", "mvp"):
+            # The sequential facades predate the warm f-cache path: seed
+            # gamma only (the init pass still scores it from scratch).
+            if warm is not None:
+                kwargs["gamma0"] = warm.gamma0
+            return solve_smo(X, spec, selection=strategy, gram_mode=gm,
+                             interpret=interpret, precision=precision, tol=tol,
+                             **kwargs)
+        if strategy == "shrinking":
+            return solve_blocked_shrinking(X, spec, P=P, gram_mode=gm,
                                            interpret=interpret,
-                                           ledger=ledger, warm=warm,
-                                           **kwargs)
-        # Below the shrinking threshold the plain sharded solve runs;
-        # surface a clear error for shrinking-only knobs instead of an
-        # opaque TypeError (the accepted kwargs must not silently change
-        # when a growing dataset crosses the threshold).
-        shrink_only = [k for k in ("warm_iters", "max_rounds",
-                                   "round_iters", "margin", "gather_max")
-                       if k in kwargs]
-        if shrink_only:
-            raise ValueError(
-                f"kwargs {shrink_only} configure the sharded shrinking "
-                f"driver, which only runs for m > {_SHRINKING_MIN_M} "
-                f"(got m={m}); drop them or call "
-                "repro.core.solve_sharded_shrinking directly")
-        return solve_blocked_distributed(X, spec, mesh,
-                                         data_axes=data_axes, P_pairs=P,
-                                         tol=tol, precision=precision,
-                                         interpret=interpret,
-                                         ledger=ledger, warm=warm,
-                                         **kwargs)
-
-    if strategy == "pallas":
-        if gram_mode is not None and gram_mode != "pallas":
-            raise ValueError(
-                f"strategy='pallas' pins gram_mode='pallas'; got "
-                f"gram_mode={gram_mode!r} — drop it or use "
-                f"strategy='blocked'")
-        return solve_blocked(X, spec, P=P, gram_mode="pallas",
-                             interpret=interpret, precision=precision,
-                             tol=tol, warm=warm, **kwargs)
-
-    gm = gram_mode if gram_mode is not None else _auto_gram_mode(m, interpret)
-    if strategy in ("paper", "mvp"):
-        # The sequential facades predate the warm f-cache path: seed
-        # gamma only (the init pass still scores it from scratch).
-        if warm is not None:
-            kwargs["gamma0"] = warm.gamma0
-        return solve_smo(X, spec, selection=strategy, gram_mode=gm,
-                         interpret=interpret, precision=precision, tol=tol,
-                         **kwargs)
-    if strategy == "shrinking":
-        return solve_blocked_shrinking(X, spec, P=P, gram_mode=gm,
-                                       interpret=interpret,
-                                       precision=precision, tol=tol,
-                                       warm=warm, **kwargs)
-    return solve_blocked(X, spec, P=P, gram_mode=gm, interpret=interpret,
-                         precision=precision, tol=tol, warm=warm, **kwargs)
+                                           precision=precision, tol=tol,
+                                           warm=warm, **kwargs)
+        return solve_blocked(X, spec, P=P, gram_mode=gm, interpret=interpret,
+                             precision=precision, tol=tol, warm=warm, **kwargs)
 
 
 def _as_artifact(prev, *, precision: str = "f32") -> SolverArtifact:

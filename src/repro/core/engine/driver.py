@@ -132,20 +132,27 @@ def run(provider, selector, stats_fn: StatsFn, state0: SolverState, *,
         return (s.it < max_iters) & unconverged & (s.stall < patience)
 
     def body(s: SolverState):
-        sel = provider.prepare(selector.select(s))
-        Kblk = provider.block(sel)
-        dsl = provider.diag_sel(sel)
-        delta = gauss_seidel_pairs(sel, Kblk, dsl, hi=hi, lo=lo)
+        # The scopes name each phase's operations in the compiled HLO's
+        # metadata (a profile viewer groups device time by them); they
+        # change no instruction.
+        with jax.named_scope("select"):
+            sel = provider.prepare(selector.select(s))
+        with jax.named_scope("pair_solve"):
+            Kblk = provider.block(sel)
+            dsl = provider.diag_sel(sel)
+            delta = gauss_seidel_pairs(sel, Kblk, dsl, hi=hi, lo=lo)
 
-        gamma_new = provider.scatter(s.gamma, sel, delta)
-        f_new = provider.apply_update(s.f, sel, delta)
+        with jax.named_scope("f_update"):
+            gamma_new = provider.scatter(s.gamma, sel, delta)
+            f_new = provider.apply_update(s.f, sel, delta)
 
-        recompute = (rho_every == 1) | ((s.it + 1) % rho_every == 0)
-        r1, r2, n_viol, max_viol, gap = stats_fn(
-            gamma_new, f_new, s.rho1, s.rho2, recompute)
+        with jax.named_scope("stats"):
+            recompute = (rho_every == 1) | ((s.it + 1) % rho_every == 0)
+            r1, r2, n_viol, max_viol, gap = stats_fn(
+                gamma_new, f_new, s.rho1, s.rho2, recompute)
 
-        progressed = jnp.max(jnp.abs(delta)) > tiny * 10
-        stall = jnp.where(progressed, 0, s.stall + 1).astype(jnp.int32)
+            progressed = jnp.max(jnp.abs(delta)) > tiny * 10
+            stall = jnp.where(progressed, 0, s.stall + 1).astype(jnp.int32)
         return SolverState(gamma_new, f_new, r1, r2, s.it + 1,
                            n_viol, max_viol, gap, stall)
 

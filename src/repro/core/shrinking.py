@@ -46,6 +46,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax import shard_map
+from jax.profiler import TraceAnnotation
 from jax.sharding import Mesh, PartitionSpec as P
 
 from repro.core.batched_smo import solve_blocked
@@ -114,70 +115,85 @@ def solve_blocked_shrinking(
                              interpret=interpret, precision=precision,
                              tol=tol, patience=patience, **kw)
 
+    # Host spans on the profiler's clock. fit.solve and fit.kkt_sweep end
+    # at a host read the driver already makes, so they cover the device
+    # work they dispatched; fit.repack and fit.rescore cover a dispatch.
     # Phase 1: bounded full-set warm solve.
-    res = _solve(Xf, spec, max_outer=warm_iters, gamma0=gamma0, warm=warm)
-    gamma = res.model.gamma
-    if bool(res.converged):
+    with TraceAnnotation("fit.solve"):
+        res = _solve(Xf, spec, max_outer=warm_iters, gamma0=gamma0,
+                     warm=warm)
+        gamma = res.model.gamma
+        converged = bool(res.converged)
+    if converged:
         return res
 
     total_iters = int(res.iters)
     for _ in range(max_rounds):
-        f = raw_scores_blocked(Xf, gamma, kernel)
-        rho1, rho2 = recover_rhos(gamma, f, spec)
-        v = _violation(gamma, f, rho1, rho2, hi=hi, lo=lo, m=m)
-        if int(jnp.sum(v > tol)) <= 1:
+        with TraceAnnotation("fit.kkt_sweep"):
+            f = raw_scores_blocked(Xf, gamma, kernel)
+            rho1, rho2 = recover_rhos(gamma, f, spec)
+            v = _violation(gamma, f, rho1, rho2, hi=hi, lo=lo, m=m)
+            n_viol = int(jnp.sum(v > tol))
+        if n_viol <= 1:
             break
 
-        # Freeze coordinates pinned at a bound with margin: at hi the KKT
-        # wants f <= lambda; it can never pair as the "down" end of a
-        # violating pair if f is below every movable-up score by margin.
-        up_ok = gamma < hi - bnd
-        dn_ok = gamma > lo + bnd
-        m_up = jnp.min(jnp.where(up_ok, f, jnp.inf))
-        m_dn = jnp.max(jnp.where(dn_ok, f, -jnp.inf))
-        frozen_hi = (~up_ok) & (f < m_up - margin * tol)
-        frozen_lo = (~dn_ok) & (f > m_dn + margin * tol)
-        frozen_zero = (jnp.abs(gamma) < bnd) & (v <= tol * 0.5)
-        frozen = (frozen_hi | frozen_lo | frozen_zero) & (v <= tol)
+        with TraceAnnotation("fit.repack"):
+            # Freeze coordinates pinned at a bound with margin: at hi the
+            # KKT wants f <= lambda; it can never pair as the "down" end
+            # of a violating pair if f is below every movable-up score by
+            # margin.
+            up_ok = gamma < hi - bnd
+            dn_ok = gamma > lo + bnd
+            m_up = jnp.min(jnp.where(up_ok, f, jnp.inf))
+            m_dn = jnp.max(jnp.where(dn_ok, f, -jnp.inf))
+            frozen_hi = (~up_ok) & (f < m_up - margin * tol)
+            frozen_lo = (~dn_ok) & (f > m_dn + margin * tol)
+            frozen_zero = (jnp.abs(gamma) < bnd) & (v <= tol * 0.5)
+            frozen = (frozen_hi | frozen_lo | frozen_zero) & (v <= tol)
 
-        active = np.asarray(~frozen)
-        n_active = int(active.sum())
-        if n_active >= int(0.9 * m) or n_active < 4 * P:
+            active = np.asarray(~frozen)
+            n_active = int(active.sum())
+            shrink = 4 * P <= n_active < int(0.9 * m)
+            if shrink:
+                # Bucket the active size by waking the least-frozen
+                # coordinates.
+                n_b = _bucket(n_active, m)
+                order = np.argsort(~active, kind="stable")  # active first
+                idx = np.sort(order[:n_b])
+                idx_j = jnp.asarray(idx)
+
+                X_act = Xf[idx_j]
+                g_act = gamma[idx_j]
+                # Frozen contribution to the active rows' scores:
+                f_act_full = f[idx_j]
+                k_act = (kernel.cross(X_act, X_act) @ g_act
+                         if n_b <= SINGLE_PASS_MAX
+                         else raw_scores_blocked(X_act, g_act, kernel))
+                f_offset = f_act_full - k_act
+        if not shrink:
             # shrinking not profitable: finish on the full set
-            res = _solve(Xf, spec, max_outer=round_iters, gamma0=gamma)
-            gamma = res.model.gamma
-            total_iters += int(res.iters)
+            with TraceAnnotation("fit.solve"):
+                res = _solve(Xf, spec, max_outer=round_iters, gamma0=gamma)
+                gamma = res.model.gamma
+                total_iters += int(res.iters)
             break
-
-        # Bucket the active size by waking the least-frozen coordinates.
-        n_b = _bucket(n_active, m)
-        order = np.argsort(~active, kind="stable")     # active first
-        idx = np.sort(order[:n_b])
-        idx_j = jnp.asarray(idx)
-
-        X_act = Xf[idx_j]
-        g_act = gamma[idx_j]
-        # Frozen contribution to the active rows' scores:
-        f_act_full = f[idx_j]
-        k_act = (kernel.cross(X_act, X_act) @ g_act
-                 if n_b <= SINGLE_PASS_MAX
-                 else raw_scores_blocked(X_act, g_act, kernel))
-        f_offset = f_act_full - k_act
 
         sub_spec = dataclasses.replace(
             spec, nu1=spec.nu1 * m / n_b, nu2=spec.nu2 * m / n_b)
-        sub = _solve(X_act, sub_spec, max_outer=round_iters, gamma0=g_act,
-                     f_offset=f_offset)
-        gamma = gamma.at[idx_j].set(sub.model.gamma)
-        total_iters += int(sub.iters)
+        with TraceAnnotation("fit.solve"):
+            sub = _solve(X_act, sub_spec, max_outer=round_iters,
+                         gamma0=g_act, f_offset=f_offset)
+            gamma = gamma.at[idx_j].set(sub.model.gamma)
+            total_iters += int(sub.iters)
 
-    f = raw_scores_blocked(Xf, gamma, kernel)
-    rho1, rho2 = recover_rhos(gamma, f, spec)
-    v = _violation(gamma, f, rho1, rho2, hi=hi, lo=lo, m=m)
-    up_ok = gamma < hi - bnd
-    dn_ok = gamma > lo + bnd
-    gap = (jnp.max(jnp.where(dn_ok, f, -jnp.inf))
-           - jnp.min(jnp.where(up_ok, f, jnp.inf)))
+    with TraceAnnotation("fit.rescore"):
+        f = raw_scores_blocked(Xf, gamma, kernel)
+        rho1, rho2 = recover_rhos(gamma, f, spec)
+        v = _violation(gamma, f, rho1, rho2, hi=hi, lo=lo, m=m)
+        up_ok = gamma < hi - bnd
+        dn_ok = gamma > lo + bnd
+        gap = (jnp.max(jnp.where(dn_ok, f, -jnp.inf))
+               - jnp.min(jnp.where(up_ok, f, jnp.inf)))
     model = OCSSVMModel(gamma=gamma, rho1=rho1, rho2=rho2, X=X32, spec=spec)
     return SMOResult(model=model, iters=jnp.asarray(total_iters),
                      n_viol=jnp.sum(v > tol).astype(jnp.int32),
@@ -313,72 +329,81 @@ def solve_sharded_shrinking(
         return sharded_raw_scores(Xf, g, kernel, mesh, data_axes=data_axes,
                                   precision=precision, ledger=ledger)
 
+    # Host spans as in the local driver.
     # Phase 1: bounded full-set distributed warm solve.
-    res = _dist(gamma0, warm_iters, warm)
-    gamma = res.model.gamma
-    if bool(res.converged):
+    with TraceAnnotation("fit.solve"):
+        res = _dist(gamma0, warm_iters, warm)
+        gamma = res.model.gamma
+        converged = bool(res.converged)
+    if converged:
         return res
 
     total_iters = int(res.iters)
     for _ in range(max_rounds):
+        with TraceAnnotation("fit.kkt_sweep"):
+            f = _scores(gamma)
+            rho1, rho2 = recover_rhos(gamma, f, spec)
+            v = _violation(gamma, f, rho1, rho2, hi=hi, lo=lo, m=m)
+            n_viol = int(jnp.sum(v > tol))
+        if n_viol <= 1:
+            break
+
+        with TraceAnnotation("fit.repack"):
+            frozen = _sharded_freeze_mask(gamma, f, v, mesh, data_axes,
+                                          hi=hi, lo=lo, tol=tol,
+                                          margin=margin, m=m, ledger=ledger)
+            active = np.asarray(~frozen)
+            n_active = int(active.sum())
+            shrink = 4 * P_pairs <= n_active < int(0.9 * m)
+            gather = shrink and n_active <= gather_max
+            if gather:
+                # The global active set fits on one shard: gather it and
+                # repack (bucketed to bound recompiles, waking the
+                # least-frozen rows to fill the bucket).
+                n_b = _bucket(n_active, m)
+                order = np.argsort(~active, kind="stable")  # active first
+                idx = np.sort(order[:n_b])
+                idx_j = jnp.asarray(idx)
+
+                X_act = Xf[idx_j]
+                g_act = gamma[idx_j]
+                k_act = (kernel.cross(X_act, X_act) @ g_act
+                         if n_b <= SINGLE_PASS_MAX
+                         else raw_scores_blocked(X_act, g_act, kernel))
+                f_offset = f[idx_j] - k_act
+        if not gather:
+            # Shrinking not profitable: finish distributed on the full
+            # set. Or the active set is still at sharded scale: another
+            # bounded distributed round, warm-started, then re-sweep.
+            with TraceAnnotation("fit.solve"):
+                res = _dist(gamma, round_iters)
+                gamma = res.model.gamma
+                total_iters += int(res.iters)
+            if shrink:
+                continue
+            break
+
+        # Continue with the LOCAL blocked solver on the repacked problem.
+        sub_spec = dataclasses.replace(
+            spec, nu1=spec.nu1 * m / n_b, nu2=spec.nu2 * m / n_b)
+        with TraceAnnotation("fit.solve"):
+            sub = solve_blocked(X_act, sub_spec, P=P_pairs,
+                                gram_mode=gram_mode, interpret=interpret,
+                                precision=precision, tol=tol,
+                                max_outer=round_iters, gamma0=g_act,
+                                f_offset=f_offset, patience=patience)
+            gamma = gamma.at[idx_j].set(sub.model.gamma)
+            total_iters += int(sub.iters)
+
+    # Final full-set verification, sharded.
+    with TraceAnnotation("fit.rescore"):
         f = _scores(gamma)
         rho1, rho2 = recover_rhos(gamma, f, spec)
         v = _violation(gamma, f, rho1, rho2, hi=hi, lo=lo, m=m)
-        if int(jnp.sum(v > tol)) <= 1:
-            break
-
-        frozen = _sharded_freeze_mask(gamma, f, v, mesh, data_axes, hi=hi,
-                                      lo=lo, tol=tol, margin=margin, m=m,
-                                      ledger=ledger)
-        active = np.asarray(~frozen)
-        n_active = int(active.sum())
-        if n_active >= int(0.9 * m) or n_active < 4 * P_pairs:
-            # Shrinking not profitable: finish distributed on the full set.
-            res = _dist(gamma, round_iters)
-            gamma = res.model.gamma
-            total_iters += int(res.iters)
-            break
-
-        if n_active > gather_max:
-            # Active set still at sharded scale: another bounded
-            # distributed round, warm-started, then re-sweep.
-            res = _dist(gamma, round_iters)
-            gamma = res.model.gamma
-            total_iters += int(res.iters)
-            continue
-
-        # The global active set fits on one shard: gather it, repack, and
-        # continue with the LOCAL blocked solver (bucketed to bound
-        # recompiles, waking the least-frozen rows to fill the bucket).
-        n_b = _bucket(n_active, m)
-        order = np.argsort(~active, kind="stable")     # active first
-        idx = np.sort(order[:n_b])
-        idx_j = jnp.asarray(idx)
-
-        X_act = Xf[idx_j]
-        g_act = gamma[idx_j]
-        k_act = (kernel.cross(X_act, X_act) @ g_act
-                 if n_b <= SINGLE_PASS_MAX
-                 else raw_scores_blocked(X_act, g_act, kernel))
-        f_offset = f[idx_j] - k_act
-
-        sub_spec = dataclasses.replace(
-            spec, nu1=spec.nu1 * m / n_b, nu2=spec.nu2 * m / n_b)
-        sub = solve_blocked(X_act, sub_spec, P=P_pairs, gram_mode=gram_mode,
-                            interpret=interpret, precision=precision,
-                            tol=tol, max_outer=round_iters, gamma0=g_act,
-                            f_offset=f_offset, patience=patience)
-        gamma = gamma.at[idx_j].set(sub.model.gamma)
-        total_iters += int(sub.iters)
-
-    # Final full-set verification, sharded.
-    f = _scores(gamma)
-    rho1, rho2 = recover_rhos(gamma, f, spec)
-    v = _violation(gamma, f, rho1, rho2, hi=hi, lo=lo, m=m)
-    up_ok = gamma < hi - bnd
-    dn_ok = gamma > lo + bnd
-    gap = (jnp.max(jnp.where(dn_ok, f, -jnp.inf))
-           - jnp.min(jnp.where(up_ok, f, jnp.inf)))
+        up_ok = gamma < hi - bnd
+        dn_ok = gamma > lo + bnd
+        gap = (jnp.max(jnp.where(dn_ok, f, -jnp.inf))
+               - jnp.min(jnp.where(up_ok, f, jnp.inf)))
     model = OCSSVMModel(gamma=gamma, rho1=rho1, rho2=rho2, X=X32, spec=spec)
     return SMOResult(model=model, iters=jnp.asarray(total_iters),
                      n_viol=jnp.sum(v > tol).astype(jnp.int32),

@@ -55,6 +55,8 @@ import time
 import warnings
 from typing import Callable, Dict, List, Optional, Tuple
 
+from jax.profiler import TraceAnnotation
+
 from repro.serve.scorer import BUCKETS
 from repro.serve.service import Pending, ScoringService
 
@@ -153,13 +155,17 @@ class AdmissionHandle:
 class _Window:
     """One model's open coalescing window."""
 
-    __slots__ = ("items", "rows", "earliest_deadline", "opened_at")
+    __slots__ = ("items", "rows", "earliest_deadline", "opened_at",
+                 "admitted_after_open")
 
     def __init__(self, now: float):
         self.items: List[Tuple[object, AdmissionHandle]] = []
         self.rows = 0
         self.earliest_deadline = math.inf
+        # the first request's admission time: the window opens with it
         self.opened_at = now
+        # summed admission times of the items, less opened_at each
+        self.admitted_after_open = 0.0
 
 
 @dataclasses.dataclass
@@ -172,6 +178,11 @@ class _WindowStats:
     dead-deadline submits (window flushed at submit time because its
     earliest deadline had already passed); ``aborted`` counts requests
     failed by ``abort_pending`` (driver crash surfacing).
+
+    ``wait_s`` sums each flushed request's queue wait, from its admission
+    to the pop of its window, on the controller's clock (over
+    ``flushed_requests``: the mean wait); ``wait_max_s`` is the longest
+    single wait.
     """
 
     opened: int = 0
@@ -181,6 +192,8 @@ class _WindowStats:
     max_rows: int = 0
     inline_flushes: int = 0
     aborted: int = 0
+    wait_s: float = 0.0
+    wait_max_s: float = 0.0
 
 
 class AdmissionController:
@@ -381,21 +394,23 @@ class AdmissionController:
             if quota is not None and not full and rows + n > quota:
                 self.rejected[model] = self.rejected.get(model, 0) + 1
                 raise QuotaExceededError(model, quota, rows, n)
+            now = self.clock()                  # the admission time
             if win is None:
                 # no window is created for a rejected request (above):
                 # an empty one would backdate the next admitted
                 # request's age under max_wait_s
-                win = self._windows[model] = _Window(self.clock())
+                win = self._windows[model] = _Window(now)
                 self._wstats(model).opened += 1
             win.items.append((q, handle))
             win.rows += n
+            win.admitted_after_open += now - win.opened_at
             if deadline is not None:
                 win.earliest_deadline = min(win.earliest_deadline,
                                             deadline)
             # Dead deadline: already passed — possibly while THIS call
             # paid the model's fit-on-first-use above. Queueing behind
             # it would wait for a poll() that may never come.
-            dead = win.earliest_deadline <= self.clock()
+            dead = win.earliest_deadline <= now
             if dead:
                 self._wstats(model).inline_flushes += 1
         if full or dead:
@@ -620,23 +635,31 @@ class AdmissionController:
                 ws.flushed_rows += win.rows
                 ws.flushed_requests += len(win.items)
                 ws.max_rows = max(ws.max_rows, win.rows)
+                # queue waits end at the pop; the first request, which
+                # opened the window, waited longest
+                waited = self.clock() - win.opened_at
+                ws.wait_s += len(win.items) * waited \
+                    - win.admitted_after_open
+                ws.wait_max_s = max(ws.wait_max_s, waited)
         if win is None or not win.items:
             return 0
-        for q, handle in win.items:
-            try:
-                handle._bind(svc.submit(q))
-            except Exception as e:
-                # Exception, NOT BaseException: KeyboardInterrupt/
-                # SystemExit must stop the loop, not be filed away.
-                # This request is permanently unservable against the
-                # CURRENT model (admission validated against the old one
-                # before a replace): fail ITS handle — result() raises —
-                # and keep serving the rest of the window. Raising here
-                # would abort poll()'s loop over other healthy models.
-                handle._fail(e)
-        if all(h._pending is None for _, h in win.items):
-            return 0
-        return svc.flush()
+        with TraceAnnotation("serve.flush"):
+            for q, handle in win.items:
+                try:
+                    handle._bind(svc.submit(q))
+                except Exception as e:
+                    # Exception, NOT BaseException: KeyboardInterrupt/
+                    # SystemExit must stop the loop, not be filed away.
+                    # This request is permanently unservable against the
+                    # CURRENT model (admission validated against the old
+                    # one before a replace): fail ITS handle — result()
+                    # raises — and keep serving the rest of the window.
+                    # Raising here would abort poll()'s loop over other
+                    # healthy models.
+                    handle._fail(e)
+            if all(h._pending is None for _, h in win.items):
+                return 0
+            return svc.flush()
 
     def forget(self, model: str) -> None:
         """Release every per-model structure for a retired name: the
@@ -695,9 +718,13 @@ class AdmissionController:
                 rej = self.rejected.get(m, 0)
                 ws = self._window_stats.get(m, _WindowStats())
                 fill = (ws.flushed_rows / ws.flushed) if ws.flushed else 0.0
+                wait = (ws.wait_s / ws.flushed_requests
+                        if ws.flushed_requests else 0.0)
                 lines.append(f"model={m},queued_rows={self.queued_rows(m)},"
                              f"rejected={rej},windows={ws.flushed}/"
-                             f"{ws.opened},mean_fill_rows={fill:.1f}")
+                             f"{ws.opened},mean_fill_rows={fill:.1f},"
+                             f"mean_wait_ms={wait * 1e3:.3f},"
+                             f"max_wait_ms={ws.wait_max_s * 1e3:.3f}")
                 svc = self._services.get(m)
                 if svc is not None:
                     lines.extend("  " + ln for ln in svc.stats_lines())

@@ -35,6 +35,8 @@ from __future__ import annotations
 import threading
 from typing import Optional, Tuple
 
+from jax.profiler import TraceAnnotation
+
 from repro.serve.admission import AdmissionController
 
 
@@ -144,7 +146,8 @@ class AsyncDriver:
                 t = ctrl.next_due_time()
                 now = ctrl.clock()
                 if t is not None and t <= now:
-                    self.step()
+                    with TraceAnnotation("serve.poll"):
+                        self.step()
                     continue
                 with self._cond:
                     if self._stop_flag:
@@ -155,11 +158,12 @@ class AsyncDriver:
                         # could sleep straight past its deadline
                         self._poke = False
                         continue
-                    if t is None:
-                        self._cond.wait()           # park: nothing can
-                        #                             become due on its own
-                    else:
-                        self._cond.wait(timeout=max(0.0, t - now))
+                    with TraceAnnotation("serve.park"):
+                        if t is None:
+                            self._cond.wait()       # park: nothing can
+                            #                         become due on its own
+                        else:
+                            self._cond.wait(timeout=max(0.0, t - now))
                     if self._stop_flag:
                         return
                     self._poke = False

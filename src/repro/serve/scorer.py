@@ -24,6 +24,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax import shard_map
+from jax.profiler import TraceAnnotation
 
 from repro.kernels.decision.ops import decision_packed
 from repro.serve.model_cache import ServingModel
@@ -91,13 +92,14 @@ class BatchScorer:
         all; jax-array inputs stay on device via jnp.pad (the pad op
         itself is trivial to compile).
         """
-        if isinstance(q, np.ndarray):
-            out = np.zeros((rows, self._d_pad), np.float32)
-            out[:q.shape[0], :q.shape[1]] = q
-            return jnp.asarray(out)
-        q = q.astype(jnp.float32)
-        return jnp.pad(q, ((0, rows - q.shape[0]),
-                           (0, self._d_pad - q.shape[1])))
+        with TraceAnnotation("serve.pad"):
+            if isinstance(q, np.ndarray):
+                out = np.zeros((rows, self._d_pad), np.float32)
+                out[:q.shape[0], :q.shape[1]] = q
+                return jnp.asarray(out)
+            q = q.astype(jnp.float32)
+            return jnp.pad(q, ((0, rows - q.shape[0]),
+                               (0, self._d_pad - q.shape[1])))
 
     @staticmethod
     def _tm(bucket: int) -> int:
@@ -174,7 +176,9 @@ class BatchScorer:
         jax-array requests keep a device result.
         """
         if host:
-            return np.asarray(out)[:n]
+            # waits for the kernel, then the device-to-host copy
+            with TraceAnnotation("serve.fetch"):
+                return np.asarray(out)[:n]
         return out[:n]
 
     def _score_once(self, q) -> Array:
@@ -182,7 +186,9 @@ class BatchScorer:
         host = isinstance(q, np.ndarray)
         if self.mesh is not None:
             return self._score_sharded(q, n)
-        out = self._score_bucket(self._pad_queries(q, bucket_for(n)))
+        q_pad = self._pad_queries(q, bucket_for(n))
+        with TraceAnnotation("serve.launch"):
+            out = self._score_bucket(q_pad)
         return self._unpad(out, n, host)
 
     # -- sharded path -------------------------------------------------------
@@ -209,7 +215,8 @@ class BatchScorer:
         nd = int(self.mesh.shape[self.data_axis])
         per_shard = bucket_for(max(1, -(-n // nd)))
         q_pad = self._pad_queries(q, per_shard * nd)
-        out = self._sharded_fn(per_shard)(q_pad, *self._replicated)
+        with TraceAnnotation("serve.launch"):
+            out = self._sharded_fn(per_shard)(q_pad, *self._replicated)
         return self._unpad(out, n, isinstance(q, np.ndarray))
 
     def warmup(self) -> None:

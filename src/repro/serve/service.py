@@ -25,6 +25,7 @@ from typing import Callable, Deque, Dict, List, Optional, Tuple
 
 import jax
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from repro.serve.scorer import BUCKETS, BatchScorer
 
@@ -32,6 +33,10 @@ from repro.serve.scorer import BUCKETS, BatchScorer
 @dataclasses.dataclass
 class BucketStats:
     """Counters for one padding bucket.
+
+    A launch's wall-clock runs from the scorer call to its scores on the
+    host: the host pad, the host-to-device copy, the kernel and the
+    device-to-host readback (``BatchScorer._score_once``).
 
     A launch recorded ``cold=True`` (the bucket's first launch on an
     un-warmed executable, which pays trace + compile) is counted in the
@@ -143,8 +148,9 @@ class ScoringService:
         # a bucket neither here nor pre-warmed on the scorer pays trace +
         # compile and is recorded cold (excluded from deadline estimates).
         self._launched: set = set()
-        # Per-group flush overhead: wall-clock spent OUTSIDE the kernel
-        # launches (concat, host transfer, scatter, done callbacks).
+        # Per-group flush overhead: wall-clock spent OUTSIDE the timed
+        # launches (concat, scatter, done callbacks; the host transfers
+        # lie inside a launch's time, see BucketStats).
         # Roughly fixed per window, so for fast models it dominates the
         # launches — an estimate built from launch means alone would
         # have the admission layer flush too late no matter the safety
@@ -252,10 +258,11 @@ class ScoringService:
                 off += chunk_rows
             scores = parts[0] if len(parts) == 1 else np.concatenate(parts)
 
-            off = 0
-            for _, p in group:
-                p._set(scores[off:off + p.n])
-                off += p.n
+            with TraceAnnotation("serve.scatter"):
+                off = 0
+                for _, p in group:
+                    p._set(scores[off:off + p.n])
+                    off += p.n
             with self._stats_lock:
                 self.flush_groups += 1
                 self.flush_overhead_s += max(

@@ -246,6 +246,44 @@ def test_window_reopens_after_flush(registry, X):
     assert w["flushed_rows"] == 3 and w["max_rows"] == 3
 
 
+def test_window_stats_hold_each_requests_queue_wait(registry, X):
+    """``wait_s`` sums each request's time from its admission to the pop
+    of its window, on the controller's clock; ``wait_max_s`` is the
+    longest. Both ride ``stats_dict`` and ``stats_lines``."""
+    clock = ManualClock()
+    ctrl = AdmissionController(registry, clock=clock, max_batch=128)
+    ctrl.service("a")
+    ctrl.submit("a", _q(X))                 # t=0: opens the window
+    clock.advance(0.25)
+    ctrl.submit("a", _q(X, seed=1))         # t=0.25
+    clock.advance(0.5)
+    ctrl.flush_model("a")                   # popped at 0.75: 0.75 + 0.5
+    clock.advance(1.0)
+    ctrl.submit("a", _q(X, seed=2))         # t=1.75, a fresh window
+    clock.advance(0.125)
+    ctrl.flush_model("a")                   # 0.125
+    w = ctrl.stats_dict()["a"]["windows"]
+    assert w["flushed_requests"] == 3
+    assert w["wait_s"] == 1.375
+    assert w["wait_max_s"] == 0.75
+    line = next(ln for ln in ctrl.stats_lines() if ln.startswith("model=a"))
+    assert "mean_wait_ms=458.333" in line and "max_wait_ms=750.000" in line
+
+
+def test_aborted_requests_add_no_queue_wait(registry, X):
+    """A request failed by ``abort_pending`` never reaches a pop: it is
+    counted as aborted and adds no wait."""
+    clock = ManualClock()
+    ctrl = AdmissionController(registry, clock=clock, max_batch=128)
+    ctrl.service("a")
+    ctrl.submit("a", _q(X))
+    clock.advance(2.0)
+    assert ctrl.abort_pending(RuntimeError("driver down")) == 1
+    w = ctrl.stats_dict()["a"]["windows"]
+    assert w["aborted"] == 1
+    assert w["wait_s"] == 0.0 and w["wait_max_s"] == 0.0
+
+
 def test_submit_during_inflight_flush_lands_in_next_window(registry, X):
     """Late arrivals join the next launch instead of blocking on the
     in-flight flush-and-wait cycle."""

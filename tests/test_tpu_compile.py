@@ -91,3 +91,32 @@ def test_f16_compiled_launch_is_refused_before_mosaic(one_chip, family):
     fn, args = _launch(family, one_chip, 30, "f16")
     with pytest.raises(ValueError, match="f16.*compiled Pallas kernel"):
         jax.jit(fn).lower(*args).compile()
+
+
+def test_solve_compiles_for_v5e_with_named_phases(one_chip):
+    """The engine loop's named scopes reach the compiled solve's metadata
+    and nothing else: the Pallas f-update inside the loop keeps the
+    custom-call name the benchmark finds it by (``fupdate.N``)."""
+    import re
+
+    from bench.lib import trace
+    from repro.core import SlabSpec, batched_smo
+    from repro.core.ocssvm import concrete_spec
+
+    m = 8192
+    spec = concrete_spec(SlabSpec(nu1=0.5, nu2=0.05, eps=0.5,
+                                  kernel=KERNEL))
+    text = batched_smo._solve_static.lower(
+        _spec(one_chip, (m, 30)), spec, P=8, gram_mode="pallas",
+        interpret=False, precision="f32", tol=1e-3, max_outer=200,
+        patience=20, gamma0=None, f_offset=None, warm=None,
+    ).compile().as_text()
+    calls = [trace.Op(trace.op_name(ln.strip()), 0, 0, ln)
+             for ln in text.splitlines() if "custom-call(" in ln
+             and "tpu_custom_call" in ln]
+    found = trace.kernel_ops(calls, "fupdate")
+    assert found and len(found) == len(calls)
+    assert all("/while/body/f_update/" in o.text for o in found)
+    scopes = set(re.findall(r'op_name="[^"]*/while/body/'
+                            r'(select|pair_solve|f_update|stats)/', text))
+    assert scopes == {"select", "pair_solve", "f_update", "stats"}

@@ -42,7 +42,7 @@ import numpy as np
 
 from repro.core.kernel_fn import KernelFn
 from repro.core.engine.types import Selection
-from repro.kernels.fupdate.ops import fupdate
+from repro.kernels.fupdate.ops import fupdate, prepare_x
 from repro.kernels.precision import check_precision, round_to_tile
 
 Array = jax.Array
@@ -264,7 +264,12 @@ class OnTheFlyGram(_ScoreDeltas):
 
 
 class PallasGram(OnTheFlyGram):
-    """on_the_fly with the rank-2P f update fused into the Pallas kernel."""
+    """on_the_fly with the rank-2P f update fused into the Pallas kernel.
+
+    The kernel's stream form of X (``kernels.fupdate.prepare_x``: cast,
+    padded to its real lane width, norms lane-dense) is built here, once
+    per provider — outside the solve's ``lax.while_loop`` — and every
+    fused call streams it as is."""
 
     name = "pallas"
 
@@ -272,13 +277,15 @@ class PallasGram(OnTheFlyGram):
                  interpret: bool | None = None, precision: str = "f32"):
         super().__init__(X, kernel, precision=precision)
         self.interpret = interpret   # None -> auto (True off-TPU)
+        self.prep = prepare_x(self.X, precision=precision,
+                              interpret=interpret)
 
     def init_scores(self, gamma: Array) -> Array:
         if self.X.shape[0] <= BLOCK:
             # f = 0 + k(X, X) @ gamma in one fused pass; the whole selected
             # block must fit VMEM, so only below the blocking threshold.
             zero = jnp.zeros((self.X.shape[0],), jnp.float32)
-            return fupdate(self.X, self.X, gamma, zero, self.kernel,
+            return fupdate(self.prep, self.X, gamma, zero, self.kernel,
                            interpret=self.interpret,
                            precision=self.precision)
         return raw_scores_blocked(self.X, gamma, self.kernel)
@@ -288,9 +295,9 @@ class PallasGram(OnTheFlyGram):
             # A selector already produced the full columns (paper rule's
             # movability mask) — reusing them beats a second HBM pass.
             return f + sel.rows @ delta
-        # self.X is already tile-rounded, so the in-kernel cast to the
-        # 16-bit stream dtype is exact — kernel and jnp paths agree.
-        return fupdate(self.X, sel.X, delta, f, self.kernel,
+        # self.X is already tile-rounded, so the cast of the selected rows
+        # to the 16-bit stream dtype is exact — kernel and jnp paths agree.
+        return fupdate(self.prep, sel.X, delta, f, self.kernel,
                        interpret=self.interpret, precision=self.precision)
 
     def delta_scores(self, f: Array, X_delta: Array,
@@ -303,7 +310,7 @@ class PallasGram(OnTheFlyGram):
             return f
         if X_delta.shape[0] > BLOCK:
             return super().delta_scores(f, X_delta, g_delta)
-        return fupdate(self.X, X_delta, g_delta, f, self.kernel,
+        return fupdate(self.prep, X_delta, g_delta, f, self.kernel,
                        interpret=self.interpret, precision=self.precision)
 
     @classmethod
@@ -329,9 +336,11 @@ class ShardedGram(_ScoreDeltas):
     Precision invariant: ``X_local`` is tile-rounded at construction
     (idempotent), and the selector feeding this provider must gather its
     candidate rows from the same rounded shard data — the distributed
-    facade rounds once, before building both. ``fupdate`` then re-casts
-    the already-rounded rows to the 16-bit stream dtype exactly, so the
-    kernel and jnp paths agree bit-for-bit on the Gram entries.
+    facade rounds once, before building both. The kernel's stream form
+    of the local rows (``prepare_x``) is built here, once per provider,
+    outside the solve's loop; ``fupdate`` casts the already-rounded
+    selected rows to the 16-bit stream dtype exactly, so the kernel and
+    jnp paths agree bit-for-bit on the Gram entries.
     """
 
     name = "sharded"
@@ -349,6 +358,8 @@ class ShardedGram(_ScoreDeltas):
         self.comm = comm
         self.axes = comm.axes
         self.interpret = interpret   # None -> auto (True off-TPU)
+        self.prep = prepare_x(self.X, precision=precision,
+                              interpret=interpret)
 
     def init_scores(self, gamma_local: Array) -> Array:
         # Local f needs the *global* K gamma: gather X and gamma once, then
@@ -384,11 +395,11 @@ class ShardedGram(_ScoreDeltas):
         # tile-rounded here and sel.X carries rows the selector gathered
         # from the SAME rounded shard data (the distributed facade rounds
         # X_local once, before building provider and selector), so the
-        # in-kernel cast to the 16-bit stream dtype is exact. fupdate's
-        # internal pads (selected block to a lane multiple, rows/features
-        # to tile multiples) carry zero deltas / zero rows and contribute
-        # exactly 0 to f (tests assert this bitwise, bf16/f16 included).
-        return fupdate(self.X, sel.X, delta, f, self.kernel,
+        # cast to the 16-bit stream dtype is exact. fupdate's pads (the
+        # selected block to a multiple of 16 rows, masked in the kernel; the
+        # prepared rows/features to tile multiples) contribute exactly 0
+        # to f (tests assert this bitwise, bf16/f16 included).
+        return fupdate(self.prep, sel.X, delta, f, self.kernel,
                        interpret=self.interpret, precision=self.precision)
 
     def scatter(self, gamma: Array, sel: Selection, delta: Array) -> Array:
@@ -409,7 +420,7 @@ class ShardedGram(_ScoreDeltas):
         if X_delta.shape[0] > BLOCK:
             return f + raw_scores_blocked(self.X, g_delta, self.kernel,
                                           Y=X_delta)
-        return fupdate(self.X, X_delta, g_delta, f, self.kernel,
+        return fupdate(self.prep, X_delta, g_delta, f, self.kernel,
                        interpret=self.interpret, precision=self.precision)
 
     def append_rows(self, X_app, gamma: Array, f: Array, g_app=None):

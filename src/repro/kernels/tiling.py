@@ -16,17 +16,19 @@ Resolution precedence, highest first:
 
 1. explicit ``tm=/tn=/tk=`` kwargs at the call site — passing ANY block
    kwarg opts the call out of the tuned table entirely (the remaining
-   fields come from :data:`DEFAULT_CONFIGS`, never from the table, so a
+   fields come from the defaults (4.), never from the table, so a
    hand-steered launch is fully predictable);
 2. ``REPRO_NO_AUTOTUNE=1`` in the environment — the escape hatch that
-   forces :data:`DEFAULT_CONFIGS` everywhere (read at trace time, like
+   forces the defaults everywhere (read at trace time, like
    ``REPRO_INTERPRET``: flip it before the first kernel call of the
    process);
 3. the committed tuned table ``tuned_configs.json`` (written by
    ``benchmarks/autotune_kernels.py --update-table``), keyed on
    ``(family, m, d, precision, backend)`` with nearest-shape fallback
    (log-distance over (m, d), capped at :data:`NEAREST_MAX_DIST`);
-4. :data:`DEFAULT_CONFIGS` — the pre-autotuner fixed constants.
+4. the defaults: :data:`DEFAULT_CONFIGS` for ``gram`` and ``decision``;
+   for ``fupdate`` tiles derived from the shapes (:func:`fupdate_tk`,
+   :func:`fupdate_tm`).
 
 Resolution happens at trace time (shapes are static under ``jit``), so
 a table swap after a shape's first trace does NOT retrace it — the
@@ -46,6 +48,8 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
+
+from repro.kernels.precision import tile_dtype
 
 # MXU/VPU lane width: every block dimension must be a multiple of this.
 LANE = 128
@@ -83,15 +87,78 @@ class TileConfig:
     source: str = "default"
 
 
-# The pre-autotuner fixed constants, still the fallback everywhere the
-# table has nothing to say. (gram: (tm, tn, tk); fupdate: (tm, -, tk);
-# decision: (tm, tn, -).)
+# The fixed constants, still the fallback of gram and decision wherever
+# the table has nothing to say. (gram: (tm, tn, tk); fupdate: (tm, -, tk);
+# decision: (tm, tn, -).) fupdate's entry only says which axes it has:
+# its default tiles come from the shapes (fupdate_tk, fupdate_tm).
 DEFAULT_CONFIGS = {
     "gram": TileConfig(256, 256, 512),
     "fupdate": TileConfig(512, None, 512),
     "decision": TileConfig(256, 512, None),
 }
 FAMILIES = tuple(DEFAULT_CONFIGS)
+
+# fupdate streams X at its real lane width: the k tile is d rounded up to
+# a lane multiple (128 lanes at d=30, 768 at d=768), one k step up to
+# this width and equal lane-multiple steps above it.
+FUPDATE_TK_CAP = 1024
+# fupdate's row tile: the largest power-of-two multiple of LANE up to
+# the cap whose VMEM working set fits the budget. The (S, TM) accumulator
+# grows with the selected block (S up to engine BLOCK = 2048 rows), so
+# TM shrinks as S grows. The budget leaves room under v5e's 16 MiB
+# default scoped VMEM.
+FUPDATE_TM_CAP = 4096
+FUPDATE_VMEM_BUDGET = 10 * 1024 * 1024
+
+
+def round_up(n: int, mult: int) -> int:
+    return -(-n // mult) * mult
+
+
+# fupdate rounds its selected block up to a multiple of this many rows:
+# one sublane tile of a 16-bit stream, two of f32. The epilogue sums the
+# block in groups of 8 rows, so its loop never runs a single trip, which
+# XLA's interpreter path would inline and fuse differently: padded rows
+# then leave f bit-identical there as on the chip.
+SEL_ROWS = 16
+
+
+def fupdate_tk(d: int) -> int:
+    """fupdate's k tile from the feature width d (see FUPDATE_TK_CAP)."""
+    dp = round_up(max(d, 1), LANE)
+    nk = -(-dp // FUPDATE_TK_CAP)
+    return round_up(-(-dp // nk), LANE)
+
+
+def fupdate_tm(m: int, s: int, tk: int, precision: str) -> int:
+    """fupdate's row tile for m rows, a selected block of s rows and a
+    k tile of tk lanes (see FUPDATE_TM_CAP)."""
+    item = jnp.dtype(tile_dtype(precision)).itemsize
+    s_pad = round_up(max(s, 1), SEL_ROWS)
+    # Resident in VMEM, double-buffered: the selected block, and its
+    # norms and deltas as (s_pad, 1) columns (one lane tile wide).
+    fixed = 2 * s_pad * tk * item + 2 * 2 * s_pad * LANE * 4
+    # Per row of the tile: the X block (two buffers), the accumulator and
+    # the dot's result (f32), and the three (1, TM) f32 vectors (norms,
+    # f, out; two buffers each, a VMEM tile of 8 sublanes).
+    per_row = 2 * tk * item + 2 * s_pad * 4 + 3 * 2 * 8 * 4
+    tm = FUPDATE_TM_CAP
+    while tm > LANE and fixed + tm * per_row > FUPDATE_VMEM_BUDGET:
+        tm //= 2
+    return min(tm, round_up(max(m, 1), LANE))
+
+
+def default_tiles(family: str, *, m: int, d: int, precision: str,
+                  s: Optional[int] = None,
+                  block_k: Optional[int] = None) -> TileConfig:
+    """A family's tiles where neither the call nor the table sets them:
+    :data:`DEFAULT_CONFIGS` for gram and decision; for fupdate, tk from
+    d (or the given ``block_k``) and tm from (m, s, tk, precision)."""
+    if family != "fupdate":
+        return DEFAULT_CONFIGS[family]
+    tk = block_k if block_k is not None else fupdate_tk(d)
+    return TileConfig(fupdate_tm(m, s if s is not None else 1, tk,
+                                 precision), None, tk)
 
 
 def _pad_to(a, mult, axis):
@@ -250,20 +317,23 @@ def lookup_tuned(family: str, m: int, d: int, precision: str,
 def resolve_tiles(family: str, *, m: int, d: int, precision: str,
                   backend: str, block_m: Optional[int] = None,
                   block_n: Optional[int] = None,
-                  block_k: Optional[int] = None) -> TileConfig:
+                  block_k: Optional[int] = None,
+                  s: Optional[int] = None) -> TileConfig:
     """Pick the launch config for one kernel call (trace time).
 
     ``m``/``d`` are the family's table key: the streamed-majority row
     count (gram: max(M, N); fupdate: the X rows; decision: the support
     rows) and the logical feature dim. ``block_*`` are the wrapper's
     explicit kwargs — any of them being set wins over the table (the
-    unset rest come from :data:`DEFAULT_CONFIGS`). See the module
+    unset rest come from the defaults). ``s`` is fupdate's selected-block
+    size, which its default row tile depends on. See the module
     docstring for the full precedence.
     """
     if family not in FAMILIES:
         raise ValueError(f"unknown kernel family {family!r}; "
                          f"expected one of {FAMILIES}")
-    default = DEFAULT_CONFIGS[family]
+    default = default_tiles(family, m=m, d=d, precision=precision, s=s,
+                             block_k=block_k)
     if block_m is not None or block_n is not None or block_k is not None:
         return replace(
             default,

@@ -27,9 +27,9 @@ from repro.core.ocssvm import SlabSpec
 from repro.kernels import decision, fupdate, gram
 from repro.kernels.autotune import (Cell, sweep, winners_to_entries,
                                     write_table)
-from repro.kernels.tiling import (DEFAULT_CONFIGS, TUNED_TABLE_PATH,
-                                  TileConfig, lookup_tuned, resolve_tiles,
-                                  set_tuned_table)
+from repro.kernels.tiling import (TUNED_TABLE_PATH, TileConfig,
+                                  default_tiles, lookup_tuned,
+                                  resolve_tiles, set_tuned_table)
 
 REPO = os.path.join(os.path.dirname(__file__), "..")
 
@@ -120,12 +120,12 @@ def test_lookup_tie_prefers_larger_m():
 # ---------------------------------------------------------------------------
 
 def test_explicit_kwargs_beat_table():
-    set_tuned_table(_table(_entry(block_m=1024, block_k=128)))
+    set_tuned_table(_table(_entry(block_m=1024, block_k=256)))
     cfg = resolve_tiles("fupdate", m=512, d=16, precision="f32",
                         backend="interpret", block_m=256)
     # any explicit kwarg opts out of the table entirely: the rest come
-    # from DEFAULT_CONFIGS (tk=512), not the table (tk=128)
-    assert cfg == TileConfig(256, None, 512, 2, "explicit")
+    # from the defaults (tk from d: 128), not the table (tk=256)
+    assert cfg == TileConfig(256, None, 128, 2, "explicit")
 
 
 def test_env_escape_hatch_beats_table(monkeypatch):
@@ -133,7 +133,7 @@ def test_env_escape_hatch_beats_table(monkeypatch):
     monkeypatch.setenv("REPRO_NO_AUTOTUNE", "1")
     cfg = resolve_tiles("fupdate", m=512, d=16, precision="f32",
                         backend="interpret")
-    assert cfg == DEFAULT_CONFIGS["fupdate"]
+    assert cfg == default_tiles("fupdate", m=512, d=16, precision="f32")
     # explicit kwargs still work under the hatch
     cfg = resolve_tiles("fupdate", m=512, d=16, precision="f32",
                         backend="interpret", block_k=128)
@@ -147,7 +147,7 @@ def test_table_then_default():
     assert (hit.block_m, hit.block_k) == (1024, 128)
     miss = resolve_tiles("fupdate", m=512, d=16, precision="f32",
                         backend="tpu")
-    assert miss == DEFAULT_CONFIGS["fupdate"]
+    assert miss == default_tiles("fupdate", m=512, d=16, precision="f32")
 
 
 # ---------------------------------------------------------------------------

@@ -262,7 +262,9 @@ def test_sharded_engine_parity_matches_blocked(precision):
     Gram tile precision — objective AND both slab offsets — and the hot
     loop must actually run the per-shard Pallas fupdate kernel (counted
     via the engine module's symbol, which ShardedGram.apply_update
-    resolves at trace time)."""
+    resolves at trace time) on the shard's X_local as the provider
+    prepared it: 12 local rows (96 over 8 shards, not a lane multiple)
+    padded to 128, 128 lanes, the stream dtype, lane-dense norms."""
     res = run_forced_devices(f"""
         import json
         import jax, jax.numpy as jnp
@@ -270,11 +272,17 @@ def test_sharded_engine_parity_matches_blocked(precision):
         import repro.core.engine.gram as eg
         from repro.core import SlabSpec, rbf, solve_blocked, dual_objective
         from repro.data import make_toy
+        from repro.kernels.fupdate.ops import PreparedX
 
-        calls = {{"n": 0}}
+        calls = {{"n": 0, "prepared": set()}}
         real_fupdate = eg.fupdate
         def counting(*a, **k):
             calls["n"] += 1
+            x = a[0]
+            calls["prepared"].add(
+                (type(x).__name__, x.m, *x.x.shape, str(x.x.dtype),
+                 *x.xn.shape) if isinstance(x, PreparedX)
+                else (type(x).__name__,))
             return real_fupdate(*a, **k)
         eg.fupdate = counting
 
@@ -294,12 +302,16 @@ def test_sharded_engine_parity_matches_blocked(precision):
             "expected_sum": spec.total(),
             "converged": bool(rs.converged),
             "fupdate_calls": calls["n"],
+            "prepared": sorted(calls["prepared"]),
             "n_devices": jax.device_count(),
         }}))
     """, devices=8)
     assert res["n_devices"] == 8
     assert res["converged"]
     assert res["fupdate_calls"] > 0, "sharded hot loop bypassed Pallas"
+    stream = {"f32": "float32", "bf16": "bfloat16", "f16": "float16"}
+    assert res["prepared"] == [
+        ["PreparedX", 12, 128, 128, stream[precision], 1, 128]]
     assert res["sum_gamma"] == pytest.approx(res["expected_sum"], abs=1e-4)
     tol_obj = truth_tolerance(precision, np.asarray([res["obj_blocked"]]))
     np.testing.assert_allclose(

@@ -13,10 +13,12 @@ from repro.core import linear, poly, rbf
 from repro.kernels import decision, fupdate, gram
 from repro.kernels.decision.ops import decision_packed
 from repro.kernels.decision.ref import decision_ref
+from repro.kernels.fupdate.ops import prepare_x
 from repro.kernels.fupdate.ref import fupdate_ref
 from repro.kernels.gram.ref import gram_ref
 from repro.kernels.precision import (PRECISIONS, round_to_tile, tile_dtype,
                                      truth_tolerance)
+from repro.kernels.tiling import fupdate_tk
 
 KERNELS = [linear(), rbf(gamma=0.35), poly(gamma=0.2, coef0=1.0, degree=2)]
 SHAPES = [(16, 8, 3), (100, 50, 7), (256, 256, 64), (300, 130, 129),
@@ -97,9 +99,18 @@ def test_gram_matches_ref(kern, shape, dtype):
                                **_tol(dtype))
 
 
+# fupdate's layout cases: m never a tile multiple; d at one lane (1), the
+# fraud width (30), one lane tile exactly (128) and one over (129), and
+# the embedding width (768); the selected block at its smallest (2), a
+# fit's 2P (16), over the 128 lanes (130) and at the engine's BLOCK (2048).
+FUPDATE_LAYOUTS = [(1000, 1, 2), (1000, 30, 16), (333, 128, 130),
+                   (333, 129, 16), (2100, 30, 2048), (500, 768, 16),
+                   (2050, 768, 2048)]
+
+
 @pytest.mark.parametrize("kern", KERNELS, ids=lambda k: k.name)
 @pytest.mark.parametrize("m,d,s", [(64, 16, 2), (200, 33, 5), (512, 128, 16),
-                                   (700, 64, 2)])
+                                   (700, 64, 2)] + FUPDATE_LAYOUTS)
 @pytest.mark.parametrize("dtype", DTYPES, ids=str)
 def test_fupdate_matches_ref(kern, m, d, s, dtype):
     keys = jax.random.split(jax.random.PRNGKey(1), 4)
@@ -184,6 +195,101 @@ def test_fupdate_pad_region_contributes_exactly_zero(kern, precision):
                       precision=precision)
     assert bool(jnp.all(out == out_pad)), (
         f"zero-padded selected rows perturbed f ({precision})")
+
+
+@pytest.mark.parametrize("kern", KERNELS, ids=lambda k: k.name)
+@pytest.mark.parametrize("m,d,s", FUPDATE_LAYOUTS)
+@pytest.mark.parametrize("precision", ["f32", "bf16"])
+def test_fupdate_prepared_x_matches_raw_and_ref(kern, m, d, s, precision):
+    """X prepared once (the providers' path) gives f bit-for-bit as X
+    prepared inside the call, and both match the dtype-matched ref. The
+    prepared form streams X at its lane width from d, keeps m rows
+    padded to a lane multiple, and holds the norms lane-dense."""
+    keys = jax.random.split(jax.random.PRNGKey(11), 4)
+    X = jax.random.normal(keys[0], (m, d), jnp.float32)
+    Xs = jax.random.normal(keys[3], (s, d), jnp.float32)
+    delta = jax.random.normal(keys[1], (s,), jnp.float32) * 0.1
+    f = jax.random.normal(keys[2], (m,), jnp.float32)
+    prep = prepare_x(X, precision=precision, interpret=True)
+    m_pad = -(-m // 128) * 128
+    assert prep.x.shape == (m_pad, fupdate_tk(d))
+    assert prep.x.dtype == tile_dtype(precision)
+    assert prep.xn.shape == (1, m_pad)
+    out = fupdate(prep, Xs, delta, f, kern, interpret=True,
+                  precision=precision)
+    raw = fupdate(X, Xs, delta, f, kern, interpret=True,
+                  precision=precision)
+    assert bool(jnp.all(out == raw))
+    ref = fupdate_ref(X, Xs, delta[:, None], f[:, None], kind=kern.name,
+                      gamma=kern.gamma, coef0=kern.coef0,
+                      degree=kern.degree, precision=precision)[:, 0]
+    np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
+                               **_MATRIX_TOL)
+
+
+def test_fupdate_prepared_x_rejects_other_precision_or_tk():
+    X = jnp.ones((200, 30), jnp.float32)
+    prep = prepare_x(X, precision="f32", interpret=True)
+    args = (X[:4], jnp.zeros((4,)), jnp.zeros((200,)), KERNELS[1])
+    with pytest.raises(ValueError, match="precision"):
+        fupdate(prep, *args, interpret=True, precision="bf16")
+    with pytest.raises(ValueError, match="tk"):
+        fupdate(prep, *args, interpret=True, tk=256)
+
+
+def _sub_jaxprs(eqn):
+    for v in eqn.params.values():
+        for sub in (v if isinstance(v, (tuple, list)) else (v,)):
+            sub = getattr(sub, "jaxpr", sub)
+            if hasattr(sub, "eqns"):
+                yield sub
+
+
+def _eqns(jaxpr):
+    """Every equation of ``jaxpr`` and its sub-jaxprs, Pallas kernel
+    bodies left out (their operands are VMEM tiles, not HBM arrays)."""
+    for e in jaxpr.eqns:
+        yield e
+        if e.primitive.name != "pallas_call":
+            for sub in _sub_jaxprs(e):
+                yield from _eqns(sub)
+
+
+@pytest.mark.parametrize("m", [256, 250], ids=["aligned", "unaligned"])
+def test_pallas_solve_loop_streams_prepared_x(m):
+    """The pallas solve's while body runs the fupdate kernel on X as the
+    provider prepared it: no pad and no norm reduction of an (m, .)
+    operand in the loop, and the f-cache reaches the kernel as a (1, m)
+    lane-dense row by a reshape (a pad of f alone when m is not a lane
+    multiple)."""
+    from repro.core import SlabSpec, solve_blocked
+    spec = SlabSpec(nu1=0.5, nu2=0.05, eps=0.5, kernel=rbf(gamma=0.5))
+    X = jax.random.normal(jax.random.PRNGKey(3), (m, 30), jnp.float32)
+    jaxpr = jax.make_jaxpr(lambda X: solve_blocked(
+        X, spec, P=4, gram_mode="pallas", interpret=True, tol=1e-3,
+        max_outer=5).model.gamma)(X).jaxpr
+    loops = [e for e in _eqns(jaxpr) if e.primitive.name == "while"
+             and any(b.primitive.name == "pallas_call"
+                     for b in _eqns(e.params["body_jaxpr"].jaxpr))]
+    assert len(loops) == 1
+    body = list(_eqns(loops[0].params["body_jaxpr"].jaxpr))
+    m_pad = -(-m // 128) * 128
+
+    def rows(e):
+        return {v.aval.shape[0] for v in e.invars
+                if getattr(v.aval, "ndim", 0) == 2}
+
+    for e in body:
+        if e.primitive.name in ("pad", "reduce_sum"):
+            assert not rows(e) & {m, m_pad}, (e.primitive, rows(e))
+    calls = [e for e in body if e.primitive.name == "pallas_call"]
+    assert len(calls) == 1
+    shapes = [v.aval.shape for v in calls[0].invars]
+    assert shapes[0] == (1, m_pad) and shapes[3] == (1, m_pad)  # xn, f
+    assert shapes[4] == (m_pad, 128)                            # X
+    pads = [e for e in body if e.primitive.name == "pad"
+            and e.invars[0].aval.shape == (m,)]
+    assert len(pads) == (0 if m == m_pad else 1)
 
 
 # -- mixed-precision parity matrix ------------------------------------------

@@ -83,6 +83,21 @@ def test_kernel_compiles_for_v5e(one_chip, family, d, precision):
     assert "tpu_custom_call" in compiled.as_text()
 
 
+@pytest.mark.parametrize("precision", PRECISIONS)
+@pytest.mark.parametrize("d", WIDTHS)
+def test_fupdate_compiles_for_v5e_at_engine_block(one_chip, d, precision):
+    """The largest selected block the engine hands ``fupdate`` (BLOCK =
+    2048 rows: ``init_scores`` at m <= BLOCK, a warm ``delta_scores``)
+    keeps its (S, TM) accumulator inside VMEM."""
+    from repro.core.engine.gram import BLOCK
+    s = partial(_spec, one_chip)
+    fn = partial(fupdate, kernel=KERNEL, interpret=False,
+                 precision=precision)
+    compiled = jax.jit(fn).lower(s((BLOCK, d)), s((BLOCK, d)), s((BLOCK,)),
+                                 s((BLOCK,))).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+
+
 @pytest.mark.parametrize("family", ("fupdate", "gram", "decision_packed"))
 def test_f16_compiled_launch_is_refused_before_mosaic(one_chip, family):
     """Mosaic refuses f16 vector loads on v5e with an internal compiler

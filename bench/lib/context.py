@@ -94,21 +94,34 @@ class Context:
                  f"longest full {self.counters['gc_full_max_s']:.4f} s")
         if self._annotation is not None:
             self._annotation.__exit__(None, None, None)
+            t = time.perf_counter()
             jax.profiler.stop_trace()
+            self.say(f"trace: written in {time.perf_counter() - t:.1f} s")
         stats = [d.memory_stats() or {} for d in self.devices]
         self.memory_peak_bytes = max(int(s.get("peak_bytes_in_use", 0))
                                      for s in stats)
         self.counters["window_lowerings"] = self.compiles.lowerings
         self.check("window_compiles", self.compiles.compiles, 0)
 
-    def reduce_trace(self) -> None:
+    def reduce_trace(self, span: Optional[str] = None) -> None:
+        """Reduce the window's trace; with ``span``, the host span of one
+        unit of work, cut to what the device record holds
+        (``trace.recorded_window``)."""
         if not self.trace:
             return
+        t = time.perf_counter()
         try:
-            self.reduced = tracing.reduce(
-                tracing.load(tracing.find_xplane(self._tmp)), kernels=KERNELS)
+            tr = tracing.load(tracing.find_xplane(self._tmp))
+            t_load = time.perf_counter() - t
+            self.reduced = r = tracing.reduce(tr, kernels=KERNELS, span=span)
         finally:
             shutil.rmtree(self._tmp, ignore_errors=True)
+        self.say(f"trace: loaded in {t_load:.1f} s, reduced in "
+                 f"{time.perf_counter() - t - t_load:.1f} s")
+        self.say(f"trace: reduced window {r.window_s!r} s, "
+                 f"{100.0 * r.recorded_share!r}% of the traced window"
+                 + (f", {r.span_count} {span} spans inside it" if span
+                    else ""))
 
     # -- results -------------------------------------------------------------
     def check(self, name: str, value: float, limit: float) -> None:

@@ -43,8 +43,10 @@ def result_line(ctx, bench: dict, wl: dict) -> dict:
                 metrics[m["name"]] = {"value": _num(v), "unit": m["unit"]}
     else:
         for m in catalog.end_to_end(bench, wl["name"]):
+            # ``<quantity>.<qualifier>`` is a runner's ``<quantity>`` under
+            # a name, and a bound, of one cell's own (``fit_s.embed``)
             v = ctx.setup_s if m["name"] == "setup_s" else ctx.e2e.get(
-                m["name"])
+                m["name"], ctx.e2e.get(m["name"].split(".")[0]))
             if v is not None:
                 metrics[m["name"]] = {"value": _num(v), "unit": m["unit"]}
     device = {"platform": dev.platform, "kind": dev.device_kind,

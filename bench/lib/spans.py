@@ -141,8 +141,11 @@ def split(intervals: List[Tuple[float, float]],
     return out
 
 
-def reduce(tr: Trace) -> Dict[str, SpanStats]:
-    lo, hi = tr.window()
+def reduce(tr: Trace, window: Optional[Tuple[float, float]] = None
+           ) -> Dict[str, SpanStats]:
+    """Per program span, inside ``window`` (the trace's own by default;
+    a fit cell's is cut to the device record, ``trace.recorded_window``)."""
+    lo, hi = window or tr.window()
     spans = program_spans(tr, lo, hi)
     stats: Dict[str, SpanStats] = {}
     for e, own in zip(spans, _self_times(spans)):
@@ -158,12 +161,14 @@ def reduce(tr: Trace) -> Dict[str, SpanStats]:
     return stats
 
 
-def idle_gaps(tr: Trace, min_s: float = 0.0
+def idle_gaps(tr: Trace, min_s: float = 0.0,
+              window: Optional[Tuple[float, float]] = None
               ) -> List[Tuple[float, float, str]]:
-    """Idle intervals of the devices inside the window at least ``min_s``
-    long, longest first: (seconds from the window's start, seconds, the
-    program span that covers most of the interval, else ``UNCOVERED``)."""
-    lo, hi = tr.window()
+    """Idle intervals of the devices inside the window (as ``reduce``
+    takes it) at least ``min_s`` long, longest first: (seconds from the
+    window's start, seconds, the program span that covers most of the
+    interval, else ``UNCOVERED``)."""
+    lo, hi = window or tr.window()
     pieces = timeline(program_spans(tr, lo, hi))
     out = []
     for ops in tr.devices.values():

@@ -11,6 +11,12 @@ thread). Everything after that is arithmetic on intervals:
   operand shapes of each launch, read from its custom call's HLO text;
 * idle gaps: the holes in the busy union inside the window, each
   labelled by the innermost host event that covers its midpoint.
+
+The profiler keeps a device's operations only up to a point: in a long
+window of short launches (a fit cell's loop) the device record stops
+while the host runs on. Given the host span of one unit of work
+(``span``, a fit), ``reduce`` cuts the window to what the record holds:
+see ``recorded_window``.
 """
 from __future__ import annotations
 
@@ -110,6 +116,27 @@ def union(intervals, lo: float, hi: float) -> List[Tuple[float, float]]:
     return [(s, e) for s, e in out]
 
 
+def recorded_window(tr: Trace, span: str) -> Tuple[float, float]:
+    """The window, cut to the device record: where a ``span`` of the
+    window opens after the last recorded device operation (on the device
+    whose record stops first), the record has stopped inside the window,
+    and the cut window ends at the end of the last ``span`` that closes
+    at or before that operation (at the operation itself if none does).
+    Otherwise the record reaches the window's end and the window stays
+    whole. A record that stops inside the window's last ``span`` cannot
+    be told from a whole one: that span's lost operations read as idle."""
+    lo, hi = tr.window()
+    if not tr.devices:
+        return lo, hi
+    last_op = min(max(o.end for o in ops) for ops in tr.devices.values())
+    units = [e for e in tr.host
+             if e.name == span and e.start >= lo and e.end <= hi]
+    if not any(e.start > last_op for e in units):
+        return lo, hi
+    return lo, max((e.end for e in units if e.end <= last_op),
+                   default=last_op)
+
+
 def busy_ns(ops: List[Op], lo: float, hi: float) -> float:
     return sum(e - s for s, e in union(((o.start, o.end) for o in ops),
                                        lo, hi))
@@ -188,16 +215,28 @@ class Reduced:
     idle_gaps: List[Tuple[str, float]]
     # launches of each kernel by their operand shapes
     kernel_shapes: Dict[str, Dict[tuple, int]] = field(default_factory=dict)
+    # the reduced window over the traced one (below 1 where it was cut)
+    recorded_share: float = 1.0
+    # the unit ``span``s wholly inside the reduced window: count, seconds
+    span_count: int = 0
+    span_s: float = 0.0
 
     @property
     def idle_share(self) -> float:
         return 1.0 - self.busy_s / self.window_s
 
 
-def reduce(tr: Trace, kernels=(), top: int = 10) -> Reduced:
-    lo, hi = tr.window()
+def reduce(tr: Trace, kernels=(), top: int = 10,
+           span: Optional[str] = None) -> Reduced:
+    """The device metrics of the window; with ``span``, of the window cut
+    to the device record (``recorded_window``), with the count and the
+    seconds of the ``span``s inside it."""
     if not tr.devices:
         raise ValueError("trace has no device operations")
+    lo, whole_hi = tr.window()
+    hi = recorded_window(tr, span)[1] if span else whole_hi
+    units = [e.end - e.start for e in tr.host
+             if span and e.name == span and e.start >= lo and e.end <= hi]
     busy = [busy_ns(ops, lo, hi) for ops in tr.devices.values()]
     kernel_s, kernel_calls, fam = {}, {}, {}
     shapes: Dict[str, Dict[tuple, int]] = {k: {} for k in kernels}
@@ -225,4 +264,6 @@ def reduce(tr: Trace, kernels=(), top: int = 10) -> Reduced:
                    busy_s=sum(busy) / len(busy) * 1e-9,
                    kernel_s=kernel_s, kernel_calls=kernel_calls,
                    device_ops=device_ops, idle_gaps=idle,
-                   kernel_shapes=shapes)
+                   kernel_shapes=shapes,
+                   recorded_share=(hi - lo) / (whole_hi - lo),
+                   span_count=len(units), span_s=sum(units) * 1e-9)

@@ -1,5 +1,7 @@
 """Share of the traced window in which no operation ran on the device,
-fit cell. Moves ``fit_s``."""
+fit cell: the window cut where the device record stops, at the end of
+the last fit it holds whole (``trace.recorded_window``). Moves
+``fit_s``."""
 
 
 def read(ro):

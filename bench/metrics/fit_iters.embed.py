@@ -1,0 +1,6 @@
+"""``fit_iters`` of the ``embed.fit`` cell, which reports its fit time as
+``fit_s.embed``: read as ``bench/metrics/fit_iters.py`` reads it. Moves
+``fit_s.embed``."""
+from bench.lib.catalog import reader
+
+read = reader("fit_iters")

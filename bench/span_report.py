@@ -5,9 +5,10 @@
         --seconds 40 --out spans-events.json
 
 Runs the cell as ``bench/run.py --trace 1`` does, and before the trace
-is deleted reduces it with ``bench/lib/spans.py``: per program span
-(``fit``, ``fit.*``, ``serve.*``) its count, total, self and idle
-seconds, and the idle gaps of at least 30 ms with the span that covers
+is deleted reduces it with ``bench/lib/spans.py`` (in a fit cell over
+the window cut to the device record, as the run's own reduction): per
+program span (``fit``, ``fit.*``, ``serve.*``) its count, total, self
+and idle seconds, and the idle gaps of at least 30 ms with the span that covers
 each. In a fit cell it also sums the device time of every operation
 inside each fit's n-th ``fit.solve`` span, by operation name, with one
 instruction text of each (to match names to a compiled module). Writes
@@ -86,8 +87,10 @@ def main(argv=None) -> None:
 
     def reduce_and_keep(tr, **kw):
         threads = tracing.Trace(tr.devices, spans.host_events(found["path"]))
-        found["spans"] = spans.reduce(threads)
-        found["gaps"] = spans.idle_gaps(tr, GAP_S)
+        win = tracing.recorded_window(tr, kw["span"]) if kw.get("span") \
+            else tr.window()
+        found["spans"] = spans.reduce(threads, win)
+        found["gaps"] = spans.idle_gaps(tr, GAP_S, win)
         if any(e.name == "fit.solve" for e in tr.host):
             found["solve_ops"] = solve_ops(tr, found["path"])
         return reduce(tr, **kw)

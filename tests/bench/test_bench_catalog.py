@@ -60,6 +60,23 @@ def test_every_cell_resolves_and_reports_what_it_must(bench):
             assert callable(catalog.reader(m["name"]))
 
 
+def test_every_fit_cell_has_the_fit_limits(bench):
+    """A fit checks each of these numbers against its configuration
+    (``cells.check_worst``): a fit cell on a configuration without them
+    could not decide ``correct``."""
+    fit_limits = {"window_compiles", "not_converged", "sum_err",
+                  "box_excess", "kkt_max", "f_rel"}
+    cells = [w for w in bench["workloads"]
+             if catalog.traffic(w["traffic"])["kind"] == "fit"]
+    assert {w["name"] for w in cells} >= {"fraud.fit", "embed.fit"}
+    for w in cells:
+        cfg = catalog.config(bench, w["config"], ROOT)
+        assert fit_limits <= set(cfg["limits"]), w["name"]
+        # the exact limits (0) need no source; the others name theirs
+        assert fit_limits - {"window_compiles", "not_converged"} <= set(
+            cfg["limit_sources"]), w["name"]
+
+
 def test_configs_name_their_source_and_cuts(bench):
     for c in bench["configs"]:
         f = ROOT / c["file"]

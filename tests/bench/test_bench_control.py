@@ -10,6 +10,8 @@ from test_bench_cells import small_cell
 
 CELLS = {"fraud.fit": ("f_rel", lambda ctx: fit_controls(ctx,
                                                           planted=False)),
+         "embed.fit": ("f_rel", lambda ctx: fit_controls(ctx,
+                                                          planted=False)),
          "fraud.events": ("kernel_sum_rel", serving_controls),
          "embed.batch": ("kernel_sum_rel", serving_controls)}
 

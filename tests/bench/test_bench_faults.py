@@ -66,15 +66,16 @@ def fit_compiles_in_the_window(X, spec, **kw):
     return REAL_FIT(X, spec, **kw)
 
 
+@pytest.mark.parametrize("name", ["fraud.fit", "embed.fit"])
 @pytest.mark.parametrize("fault,expect", [
     (fit_state_unchanged, "kkt_max"),
     (fit_answer_altered, "f_rel"),
     (fit_half_the_rows, "kkt_max"),
     (fit_compiles_in_the_window, "window_compiles"),
 ])
-def test_fit_fault_is_not_correct(fault, expect, monkeypatch):
+def test_fit_fault_is_not_correct(fault, expect, name, monkeypatch):
     monkeypatch.setattr(repro, "fit", fault)
-    _, _, ctx = small_cell("fraud.fit", seconds=0.5)
+    _, _, ctx = small_cell(name, seconds=0.5)
     assert not ctx.correct
     assert expect in _failed(ctx), _checks(ctx)
 

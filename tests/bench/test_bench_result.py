@@ -42,7 +42,8 @@ def _ctx(name, trace_on):
             window_s=20.0, busy_s=19.5, kernel_s={"fupdate": 9.0},
             kernel_calls={"fupdate": 20_700},
             kernel_shapes={"fupdate": {FUPDATE_SHAPES: 20_700}},
-            device_ops=[("fupdate.6", 9.0)], idle_gaps=[("none", 0.25)])
+            device_ops=[("fupdate.6", 9.0)], idle_gaps=[("none", 0.25)],
+            span_count=3, span_s=18.75)
     return bench, wl, ctx
 
 
@@ -97,3 +98,20 @@ def test_readers_return_nothing_without_their_source():
     for m in catalog.per_layer(bench, "fraud.events") + catalog.per_layer(
             bench, "embed.batch"):
         assert catalog.reader(m["name"])(ro) is None
+
+
+def test_a_cell_reads_its_own_fit_metrics_as_the_runner_reports_them():
+    """``embed.fit`` reports ``fit_s`` and the fit readers under names of
+    its own (``<name>.embed``), each reading what the plain name reads."""
+    bench, wl, ctx = _ctx("embed.fit", True)
+    _, fwl, fctx = _ctx("fraud.fit", True)
+    own = result_line(ctx, bench, wl)["metrics"]
+    plain = result_line(fctx, bench, fwl)["metrics"]
+    assert set(own) == {m["name"] for m in catalog.per_layer(bench,
+                                                             "embed.fit")}
+    assert own and all(v == plain[k[:-len(".embed")]]
+                       for k, v in own.items())
+    ctx.trace, ctx.reduced = False, None
+    assert result_line(ctx, bench, wl)["metrics"] == {
+        "fit_s.embed": {"value": 6.25, "unit": "s"},
+        "setup_s": {"value": 12.5, "unit": "s"}}

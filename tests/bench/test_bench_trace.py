@@ -126,3 +126,76 @@ def test_trace_without_window_or_devices_is_refused():
     with pytest.raises(ValueError):
         trace.reduce(trace.Trace(host=[trace.HostEvent(
             trace.WINDOW_SPAN, 0, 1, "python")]))
+
+
+FIT = "bench.fit"
+FUPDATE = ("%fupdate.6 = f32[1,512]{1,0} custom-call(f32[512,8]{1,0} "
+           "%x, f32[16,8]{1,0} %s), custom_call_target=\"tpu_custom_call\"")
+
+
+def fit_trace(last_op: float) -> trace.Trace:
+    """A fit cell's window [0, 1000] ns: five fits of 190 ns, each after
+    a 10 ns generation of its rows. The device runs a 50 ns ``fupdate``
+    launch and a 30 ns fusion in each fit, and its record stops at
+    ``last_op``."""
+    host = [trace.HostEvent(trace.WINDOW_SPAN, 0, 1000, "python")]
+    ops = []
+    for i in range(5):
+        t = 200 * i
+        host += [trace.HostEvent("bench.gen", t, t + 10, "python"),
+                 trace.HostEvent(FIT, t + 10, t + 200, "python")]
+        ops += [_op("fupdate.6", t + 20, t + 70, FUPDATE),
+                _op("fusion.1", t + 150, t + 180)]
+    return trace.Trace(devices={"/device:TPU:0": [o for o in ops
+                                                  if o.end <= last_op]},
+                       host=host)
+
+
+def _mfu(r):
+    from bench.lib.peaks import peaks_for
+    from bench.lib.catalog import reader
+
+    class Ro:
+        counters = {"m": 512, "d": 8, "pairs": 8}
+        trace = r
+        peaks = peaks_for("TPU v5 lite")
+    return reader("mfu.fit")(Ro())
+
+
+def test_a_record_that_stops_mid_window_cuts_the_fit_window():
+    # the record stops inside the third fit, after its fupdate launch
+    tr = fit_trace(last_op=480)
+    assert trace.recorded_window(tr, FIT) == (0, 400)
+    r = trace.reduce(tr, kernels=("fupdate",), span=FIT)
+    assert r.window_s == pytest.approx(400e-9)
+    assert r.recorded_share == pytest.approx(0.4)
+    assert (r.span_count, r.span_s) == (2, pytest.approx(380e-9))
+    assert r.busy_s == pytest.approx(2 * 80e-9)
+    assert r.kernel_calls == {"fupdate": 2}
+    assert r.kernel_s["fupdate"] == pytest.approx(100e-9)
+    assert r.idle_share == pytest.approx(1 - 160 / 400)
+    # no gap runs on into the part the record lost
+    assert sum(s for _, s in r.idle_gaps) == pytest.approx(240e-9)
+    assert max(s for _, s in r.idle_gaps) == pytest.approx(80e-9)
+    # mfu.fit counts the two recorded fits over their own 380 ns
+    m, d, s = 512, 8, 16
+    flops = 2 * (2.0 * m * m * d) + 2 * (2.0 * m * s * d + 2.0 * m * s)
+    assert _mfu(r) == pytest.approx(100 * flops / (380e-9 * 197e12))
+    # without a span the window is the whole traced one, as before
+    assert trace.reduce(tr, kernels=("fupdate",)).window_s == \
+        pytest.approx(1000e-9)
+
+
+def test_a_whole_record_reduces_as_it_did_without_the_cut(recorded):
+    # the last operation ends before the last fit's host span closes
+    tr = fit_trace(last_op=1000)
+    assert trace.recorded_window(tr, FIT) == (0, 1000)
+    cut = trace.reduce(tr, kernels=("fupdate",), span=FIT)
+    whole = trace.reduce(tr, kernels=("fupdate",))
+    assert (cut.span_count, cut.span_s) == (5, pytest.approx(950e-9))
+    assert cut.recorded_share == 1.0
+    cut.span_count, cut.span_s = whole.span_count, whole.span_s
+    assert cut == whole
+    # a trace with no fit spans (the fixture) keeps its whole window
+    assert trace.reduce(recorded, kernels=KERNELS, span=FIT) == \
+        trace.reduce(recorded, kernels=KERNELS)
